@@ -1,6 +1,7 @@
-"""Pallas kernel micro-benchmarks (interpret mode on CPU; structural —
-real perf numbers require a TPU).  Derived column reports agreement with
-the jnp oracle so the CSV doubles as a correctness gate.
+"""Pallas kernel micro-benchmarks (compiled on a TPU, interpreted
+elsewhere; CPU rows are structural — real perf numbers require a TPU).
+Derived column reports agreement with the jnp oracle so the CSV doubles
+as a correctness gate.
 
 Also writes ``BENCH_kernels.json`` with the loop-vs-bitonic extraction
 scaling table: per-block sequential work and (where feasible) wall-clock
@@ -18,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import row, time_call
-from repro.kernels import ops, ref
+from repro.kernels import interpret_mode, ops, ref
 from repro.kernels.bitonic import next_pow2
 from repro.kernels.sparsify_ef import TILE
 
@@ -34,7 +35,7 @@ EXTRACT_KS = (1024, 4096, 16384, 65536)
 def _bitonic_serial_steps(block: int, n_slots: int) -> int:
     """Sequential depth of the bitonic extractor: two full sorting
     networks (log2(n2)(log2(n2)+1)/2 compare-exchange stages each — all
-    pairs per stage run lanes-parallel) plus one cumsum per slot."""
+    pairs per stage run lanes-parallel) plus one count per slot."""
     lg = next_pow2(block).bit_length() - 1
     return 2 * (lg * (lg + 1) // 2) + n_slots
 
@@ -154,7 +155,7 @@ def main(argv=None):
     scaling, failures = extraction_scaling()
     device = jax.devices()[0]
     report = {
-        "interpret": True,
+        "interpret": interpret_mode(),
         "device_kind": device.device_kind,
         "platform": device.platform,
         "loop_time_max_k": LOOP_TIME_MAX_K,
@@ -162,11 +163,12 @@ def main(argv=None):
                  "cost, loop vs bitonic, each at the block size the hot "
                  "path picks for that k.  serial_steps is the "
                  "structural sequential depth (loop: n_cand global "
-                 "reductions; bitonic: 2 sorting networks + one cumsum "
-                 "per slot — independent of k); us is interpret-mode "
-                 "wall-clock, null where the loop is infeasible (the "
-                 "scaling failure the bitonic backend removes).  exact "
-                 "gates indices AND values bitwise vs lax.top_k."),
+                 "reductions; bitonic: 2 sorting networks + one count "
+                 "per slot — independent of k); us is wall-clock on "
+                 "this device (interpret-mode off a TPU), null where "
+                 "the loop is infeasible (the scaling failure the "
+                 "bitonic backend removes).  exact gates indices AND "
+                 "values bitwise vs lax.top_k."),
         "extraction_scaling": scaling,
     }
     with open(args.out, "w") as f:

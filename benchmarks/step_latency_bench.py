@@ -15,13 +15,12 @@ quantize->pack path, pallas_call count jaxpr-asserted == 1) and record
 the ``--wire-buckets`` overlapped-exchange pricing (per-node wire bytes
 + explicit padding overhead at that pipeline depth).
 
-Timings default to interpret-mode on CPU, so the *absolute* numbers are
+The kernels compile on a TPU and run in the Pallas interpreter
+elsewhere (``repro.kernels.interpret_mode``), so CPU timings are
 structural (launch counts, pass structure), not TPU wall-clock; the
-derived ``max_err_vs_jnp`` column is exact either way.  On a real
-accelerator pass ``--compiled`` to drop ``interpret=True`` and get
-wall-clock rows; the artifact records ``device_kind``/``interpret`` so
-CPU-interpret rows and real-TPU rows are distinguishable in the
-trajectory.
+derived ``max_err_vs_jnp`` column is exact either way.  The artifact
+records ``device_kind``/``interpret`` so CPU-interpret rows and TPU
+rows are distinguishable in the trajectory.
 """
 from __future__ import annotations
 
@@ -36,6 +35,7 @@ from repro.configs.base import CompressionConfig
 from repro.core import build_compressor
 from repro.core.phases import phase_for_step
 from repro.core.sparsify import build_layout, fused_plan_info
+from repro.kernels import count_pallas_calls, interpret_mode
 
 PARAMS = {
     "embed": {"w": jnp.zeros((128, 64))},
@@ -50,15 +50,13 @@ STEPS = 4                       # warmup(1) -> topk+AE(2) -> compressed
 TOL = 1e-5
 
 
-def run_method(method: str, backend: str, ae_backend: str = "jnp",
-               interpret: bool = True):
+def run_method(method: str, backend: str, ae_backend: str = "jnp"):
     """Full phase schedule; returns (stacked global grads, final u, v,
     us_per_step of the steady-state last-phase step)."""
     cc = CompressionConfig(method=method, sparsity=0.02,
                            innovation_sparsity=0.002, warmup_steps=1,
                            ae_train_steps=2, topk_backend=backend,
-                           ae_backend=ae_backend,
-                           topk_interpret=interpret)
+                           ae_backend=ae_backend)
     comp = build_compressor(cc, PARAMS, K)
     n = comp.layout.n_total
     states = comp.init_sim_states(jax.random.PRNGKey(0))
@@ -78,28 +76,7 @@ def run_method(method: str, backend: str, ae_backend: str = "jnp",
     return jnp.stack(gs), states["u"], states["v"], us
 
 
-def _count_pallas(jaxpr) -> int:
-    """Recursive ``pallas_call`` count through pjit/scan sub-jaxprs —
-    the launch-structure metric the fused-encode rows record."""
-    def subs(v):
-        if hasattr(v, "jaxpr"):                    # ClosedJaxpr
-            return [v.jaxpr]
-        if hasattr(v, "eqns"):                     # Jaxpr
-            return [v]
-        if isinstance(v, (list, tuple)):
-            return [j for x in v for j in subs(x)]
-        return []
-    n = 0
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            n += 1
-        for v in eqn.params.values():
-            for sub in subs(v):
-                n += _count_pallas(sub)
-    return n
-
-
-def packed_encode_rows(report, wire_buckets, interpret=True):
+def packed_encode_rows(report, wire_buckets):
     """The packed-wire encode collapsed into ONE kernel: time the
     composed multi-pass path (block-quantize, then bit-plane pack —
     separate HBM round-trips) against ``packed.encode_sparse_fused``,
@@ -117,17 +94,17 @@ def packed_encode_rows(report, wire_buckets, interpret=True):
     pack = PK.make_plan(n, k, 256)
 
     def composed(v, i):
-        return PK.encode_sparse(v, i, pack, interpret=interpret)
+        return PK.encode_sparse(v, i, pack)
 
     def fused(v, i):
-        return PK.encode_sparse_fused(v, i, pack, interpret=interpret)
+        return PK.encode_sparse_fused(v, i, pack)
 
     idx = jnp.sort(jax.random.choice(jax.random.PRNGKey(3), n, (k,),
                                      replace=False).astype(jnp.int32))
     vals = jax.random.normal(jax.random.PRNGKey(4), (k,))
     ref, got = composed(vals, idx), fused(vals, idx)
     bitwise = all(bool(jnp.all(a == b)) for a, b in zip(ref, got))
-    launches = {name: _count_pallas(jax.make_jaxpr(f)(vals, idx).jaxpr)
+    launches = {name: count_pallas_calls(jax.make_jaxpr(f)(vals, idx))
                 for name, f in (("composed", composed), ("fused", fused))}
     us_c = time_call(jax.jit(composed), vals, idx)
     us_f = time_call(jax.jit(fused), vals, idx)
@@ -163,17 +140,11 @@ def main(argv=None):
                    help="bucket count for the overlapped-exchange "
                         "pricing rows (wire bytes + padding at this "
                         "pipeline depth vs unbucketed)")
-    p.add_argument("--compiled", action="store_true",
-                   help="compile the Pallas kernels (drop interpret=True)"
-                        " when a real accelerator is present; on CPU the "
-                        "flag is ignored (interpret mode is the only way "
-                        "the kernels run there)")
     # tolerate foreign flags when run via benchmarks.run's module loop
     args, _ = p.parse_known_args(argv)
 
     device = jax.devices()[0]
-    compiled = bool(args.compiled) and device.platform != "cpu"
-    interpret = not compiled
+    interpret = interpret_mode()
 
     # self-describing artifact: device_kind + interpret distinguish
     # interpret-mode CPU rows (structural: launch counts, pass
@@ -188,7 +159,7 @@ def main(argv=None):
         "platform": device.platform,
         "note": (("us_per_step timings are compiled on "
                   f"{device.device_kind}: real wall-clock rows")
-                 if compiled else
+                 if not interpret else
                  ("us_per_step timings are Pallas interpret-mode on "
                   f"{device.device_kind}: structural (launch counts, "
                   "pass structure), NOT accelerator wall-clock — "
@@ -204,7 +175,7 @@ def main(argv=None):
     plan = fused_plan_info(build_layout(PARAMS, 0.02))
     failures = []
     for method in METHODS:
-        oracle = run_method(method, "jnp", interpret=interpret)
+        oracle = run_method(method, "jnp")
         # "none" never touches a selection kernel: one baseline row only
         variants = [("jnp", "jnp", "jnp")] if method == "none" \
             else [(b, "jnp", b) for b in BACKENDS]
@@ -214,8 +185,7 @@ def main(argv=None):
         entry = {}
         for backend, ae_backend, label in variants:
             res = oracle if (backend, ae_backend) == ("jnp", "jnp") \
-                else run_method(method, backend, ae_backend,
-                                interpret=interpret)
+                else run_method(method, backend, ae_backend)
             gs, u, v, us = res
             err = max(float(jnp.max(jnp.abs(a - b)))
                       for a, b in zip(oracle[:3], (gs, u, v)))
@@ -229,8 +199,7 @@ def main(argv=None):
                 failures.append((method, label, err))
         report["methods"][method] = entry
 
-    if not packed_encode_rows(report, args.wire_buckets,
-                              interpret=interpret):
+    if not packed_encode_rows(report, args.wire_buckets):
         failures.append(("packed_encode",
                          report["packed_encode"]["launches"],
                          report["packed_encode"]["bit_exact"]))

@@ -38,6 +38,7 @@ from benchmarks.common import row, time_call
 from repro.configs.base import CompressionConfig
 from repro.core import build_compressor
 from repro.core.phases import PHASE_COMPRESSED, PHASE_TOPK_AE
+from repro.dist.transport import EXACT_TOL, Q8_TOL
 PARAMS = {
     "embed": {"w": jnp.zeros((128, 64))},
     "layer1": {"w": jnp.zeros((256, 256)), "b": jnp.zeros((256,))},
@@ -45,13 +46,9 @@ PARAMS = {
     "lm_head": {"w": jnp.zeros((64, 128))},
 }
 K = 4
-# ring_q8's compressed-phase gradient differs from the fake-quant Sim
-# oracle by the wire's bounded requantization error, and ring_packed's
-# sparse exchanges by their single int8 value quantization (measured
-# ~3e-4 at this scale; see tests/test_transports.py) — everything else
-# is exact
-Q8_TOL = 2e-3
-EXACT_TOL = 1e-5
+# gates against the Sim oracle: repro.dist.transport.EXACT_TOL/Q8_TOL
+# (ring_packed's one value quantization measured ~3e-4 at this scale;
+# see tests/test_transports.py)
 
 
 def sim_latency_rows():
@@ -128,7 +125,7 @@ def _traced_subprocess(code: str, devices: int) -> str:
     import os
     import subprocess
     import sys
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", code], env=env,
@@ -387,7 +384,7 @@ for method, wb in (("dgc", 1), ("lgc_rar", 1), ("lgc_rar_q8", 1),
           " ".join(f"{{t}}={{worst[t]:.2e}}" for t in transports))
 print("GATE-PASS")
 """
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", code], env=env,

@@ -1,10 +1,2 @@
 """Reproduction framework for "Learned Gradient Compression for
-Distributed Deep Learning" on the jax/pallas stack.
-
-Importing the package installs the jax-version compatibility shims (see
-:mod:`repro.compat`) so every module — and the test-suite — can be written
-against the modern jax API surface regardless of the container pin.
-"""
-from repro import compat as _compat
-
-_compat.install()
+Distributed Deep Learning" on the jax/pallas stack."""

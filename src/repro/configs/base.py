@@ -204,11 +204,10 @@ class CompressionConfig:
     # "pallas" (kernels/ops.global_topk, one launch per leaf) or "fused"
     # (the single-sweep segmented kernel: EF accumulate + per-leaf
     # selection of every exempt+compressed leaf in ONE launch — see
-    # DESIGN.md "The fused sparsification sweep").  topk_interpret=False
-    # runs ALL Pallas kernels — selection and the ae_backend encoder —
-    # compiled (real TPUs); True interprets them (CPU).
+    # DESIGN.md "The fused sparsification sweep").  Every Pallas kernel
+    # compiles on a TPU backend and is interpreted elsewhere
+    # (repro.kernels.interpret_mode).
     topk_backend: str = "jnp"
-    topk_interpret: bool = True
     # fused sweep's per-block candidate extraction: "loop" (sequential
     # max->record->mask, O(k) reductions per block — cheapest at small
     # k), "bitonic" (the lanes-parallel sorting network in

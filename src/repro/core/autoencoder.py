@@ -48,7 +48,8 @@ def init_lgc_autoencoder(key, num_decoders: int = 1,
                          ps_innovation: bool = False) -> Dict:
     """AE params. num_decoders=K for the PS pattern (one decoder per node,
     Section IV-A); 1 for RAR.  ps_innovation adds the innovation channel to
-    the final conv of each decoder."""
+    the final conv of each decoder, and the PS decoders are always
+    stacked along a leading K axis — also at K = 1."""
     keys = jax.random.split(key, 16)
     enc, c_in = [], 1
     for i, (c_out, k, _s) in enumerate(ENCODER_SPEC):
@@ -68,7 +69,7 @@ def init_lgc_autoencoder(key, num_decoders: int = 1,
                     "b": jnp.zeros((1,))})
         return dec
 
-    if num_decoders == 1:
+    if num_decoders == 1 and not ps_innovation:
         decoders = one_decoder(keys[10])
     else:
         decoders = jax.vmap(one_decoder)(

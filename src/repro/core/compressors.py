@@ -143,13 +143,11 @@ class GradientCompressor:
     def _select(self, v):
         return SP.select_topk(v, self.layout,
                               backend=self.cc.topk_backend,
-                              interpret=self.cc.topk_interpret,
                               extract=self.cc.extract_backend)
 
     def _select_last(self, v):
         return SP.select_topk_last(v, self.layout,
                                    backend=self.cc.topk_backend,
-                                   interpret=self.cc.topk_interpret,
                                    extract=self.cc.extract_backend)
 
     def _fused_sweep(self, u, v, g):
@@ -158,15 +156,13 @@ class GradientCompressor:
         return SP.fused_accumulate_select(
             g, u, v, self.layout, self.cc.momentum_correction,
             use_momentum=self._use_momentum,
-            interpret=self.cc.topk_interpret,
             extract=self.cc.extract_backend)
 
     def _encode(self, ae, x):
         assert self.cc.ae_backend in ("jnp", "pallas"), self.cc.ae_backend
         if self.cc.ae_backend == "pallas":
             from repro.kernels import ops as K_ops
-            return K_ops.lgc_encode_fast(ae, x,
-                                         interpret=self.cc.topk_interpret)
+            return K_ops.lgc_encode_fast(ae, x)
         return AE.lgc_encode(ae, x)[0]                   # (mu/16, 4)
 
     # -- AE online training (phase 2, Section V-B) -----------------------------
@@ -481,7 +477,6 @@ class GradientCompressor:
                            scale_block=self.cc.q8_scale_block,
                            intra_chunk=self.cc.ring_intra_chunk,
                            inter_chunk=self.cc.ring_inter_chunk,
-                           interpret=self.cc.topk_interpret,
                            guard=self.cc.guard,
                            wire_buckets=self.cc.wire_buckets, fault=spec)
         return self.step(t, state, g, step, phase)
@@ -494,7 +489,6 @@ class GradientCompressor:
         kind = "chaos:sim" if spec is not None else "sim"
         t = make_transport(kind, self.K,
                            scale_block=self.cc.q8_scale_block,
-                           interpret=self.cc.topk_interpret,
                            guard=self.cc.guard, fault=spec)
         return self.step(t, states, g_nodes, step, phase)
 

@@ -169,8 +169,8 @@ SELECT_BACKENDS = ("jnp", "pallas", "fused")
 FUSED_BLOCK = _SEG_BLOCK
 # VMEM ceiling for the scaled block: the fused kernel keeps ~6
 # block-sized f32/int32 tiles resident per grid step (~24*block bytes),
-# so 128Ki elements ≈ 3 MiB — safely under a TPU core's ~16 MiB when
-# compiled (interpret=False)
+# so 128Ki elements ≈ 3 MiB; both extractors compile for a v5e at this
+# block within the default scoped VMEM limit (tests/test_tpu_compile.py)
 FUSED_BLOCK_MAX = 128 * 1024
 
 # per-block candidate-extraction backends (kernels/segmented_topk vs
@@ -216,8 +216,7 @@ def _fused_block(slots, extract: str = "loop") -> int:
 def _fused_meta(layout: GradientLayout, roles: Tuple[str, ...],
                 extract: str = "auto"):
     """Static segmented-sweep metadata for ``layout``: the resolved
-    extraction backend, the block size, the element->slot map (numpy,
-    becomes a trace-time constant), per-slot top-k caps, and the exact
+    extraction backend, the block size, per-slot top-k caps, and the exact
     per-block candidate budget (worst case over blocks of sum_slots
     min(k_slot, |slot piece in block|) — the pigeonhole bound that makes
     the merged result exact)."""
@@ -226,9 +225,6 @@ def _fused_meta(layout: GradientLayout, roles: Tuple[str, ...],
     ex = _resolve_extract(extract, slots)
     block = _fused_block(slots, ex)
     n_pad = -(-layout.n_total // block) * block
-    seg = np.full((n_pad,), -1, np.int32)
-    for j, leaf in enumerate(slots):
-        seg[leaf.offset:leaf.offset + leaf.size] = j
     kcap = np.asarray([l.k for l in slots], np.int32)
     # per-block candidate budget: each slot's piece size in block b is a
     # range overlap, so the budget is computed analytically per slot
@@ -242,7 +238,19 @@ def _fused_meta(layout: GradientLayout, roles: Tuple[str, ...],
                   - np.maximum(leaf.offset, bs * block))
         budget[b0:b1 + 1] += np.minimum(pieces, leaf.k)
     n_cand = max(1, int(budget.max(initial=0)))
-    return ex, block, seg[:layout.n_total], kcap, n_cand, slots
+    return ex, block, kcap, n_cand, slots
+
+
+def _slot_map(n: int, slots) -> jnp.ndarray:
+    """Element -> slot id over a length-``n`` vector (-1 = not
+    selectable), computed from an iota at run time: as a trace-time
+    constant the map would put 4n bytes into the executable."""
+    pos = jax.lax.iota(jnp.int32, n)
+    seg = jnp.full((n,), -1, jnp.int32)
+    for j, leaf in enumerate(slots):
+        seg = jnp.where((pos >= leaf.offset)
+                        & (pos < leaf.offset + leaf.size), j, seg)
+    return seg
 
 
 def fused_plan_info(layout: GradientLayout,
@@ -252,15 +260,15 @@ def fused_plan_info(layout: GradientLayout,
     """Self-describing sweep plan for bench artifacts: the chosen block
     size, per-block candidate-pool bound, and resolved extraction
     backend for ``layout`` (same derivation the hot path uses)."""
-    ex, block, _, _, n_cand, _ = _fused_meta(layout, roles, extract)
+    ex, block, _, n_cand, _ = _fused_meta(layout, roles, extract)
     return {"fused_block": block, "n_cand": n_cand, "extract_backend": ex}
 
 
 def _merge_candidates(cvals, cidx, cseg, slots):
     """Exact per-slot top-k from the one-sweep candidate pool.  The
-    per-leaf lax.top_k here runs over the tiny candidate arrays
-    (n_blocks*n_cand elements, VMEM-scale), not the full vector — the
-    same merge shape ops.global_topk uses."""
+    per-leaf lax.top_k here runs over the candidate arrays
+    (n_blocks*n_cand elements), not the full vector — the same merge
+    shape ops.global_topk uses."""
     mags = jnp.abs(cvals)
     vals_list, idx_list = [], []
     for j, leaf in enumerate(slots):
@@ -277,11 +285,11 @@ def _fused_select_lists(v: jnp.ndarray, layout: GradientLayout,
     """Per-leaf (vals, idx) lists for all leaves of ``roles`` via ONE
     segmented-sweep kernel launch."""
     from repro.kernels import ops as K_ops
-    ex, block, seg, kcap, n_cand, slots = _fused_meta(layout, roles,
-                                                      extract)
+    ex, block, kcap, n_cand, slots = _fused_meta(layout, roles, extract)
     if not slots:
         return [], []
-    cv, ci, cs = K_ops.segmented_topk(v, jnp.asarray(seg),
+    seg = _slot_map(layout.n_total, slots)
+    cv, ci, cs = K_ops.segmented_topk(v, seg,
                                       jnp.asarray(kcap), n_cand=n_cand,
                                       block=block, extract=ex,
                                       interpret=interpret)
@@ -314,7 +322,7 @@ def _pad_compressed(vals_list, idx_list, layout, dtype):
 
 
 def select_topk(v: jnp.ndarray, layout: GradientLayout,
-                backend: str = "jnp", interpret: bool = True,
+                backend: str = "jnp", interpret: bool | None = None,
                 extract: str = "auto"):
     """Top-k per compressed leaf of the residual vector ``v``.
 
@@ -324,8 +332,8 @@ def select_topk(v: jnp.ndarray, layout: GradientLayout,
     ONE launch for the whole vector).  All are exact and return the same
     ordering (ties break lowest-index-first).  ``extract`` picks the
     fused sweep's per-block extraction ("auto" | "loop" | "bitonic" —
-    see EXTRACT_BACKENDS; ignored by the other backends).  Pass
-    ``interpret=False`` on real TPUs.
+    see EXTRACT_BACKENDS; ignored by the other backends).
+    ``interpret`` overrides the platform default (compiled on TPU).
 
     Returns (values (mu_pad,), indices (mu_pad,) int32).  Padding entries
     carry value 0 and sentinel index n_total (dropped by scatters).
@@ -341,7 +349,7 @@ def select_topk(v: jnp.ndarray, layout: GradientLayout,
 
 
 def select_topk_last(v: jnp.ndarray, layout: GradientLayout,
-                     backend: str = "jnp", interpret: bool = True,
+                     backend: str = "jnp", interpret: bool | None = None,
                      extract: str = "auto"):
     """Top-k over the exempt last layer(s) (sent raw, no AE), through the
     same backend dispatch as :func:`select_topk`."""
@@ -361,7 +369,7 @@ def select_topk_last(v: jnp.ndarray, layout: GradientLayout,
 def fused_accumulate_select(g: jnp.ndarray, u: jnp.ndarray, v: jnp.ndarray,
                             layout: GradientLayout, momentum: float,
                             use_momentum: bool = True,
-                            interpret: bool = True,
+                            interpret: bool | None = None,
                             extract: str = "auto"):
     """THE fused hot path (``topk_backend="fused"``): one kernel sweep
     does the EF accumulate (u' = m*u + g, v' = v + u'; plain residual
@@ -374,8 +382,7 @@ def fused_accumulate_select(g: jnp.ndarray, u: jnp.ndarray, v: jnp.ndarray,
     launch per leaf, in one read of (g, u, v) and one write of (u', v').
     """
     roles = (ROLE_COMPRESSED, ROLE_TOPK_ONLY)
-    ex, block, seg, kcap, n_cand, slots = _fused_meta(layout, roles,
-                                                      extract)
+    ex, block, kcap, n_cand, slots = _fused_meta(layout, roles, extract)
     if not slots:                        # degenerate: nothing selectable
         # (no compressed and no topk_only leaves => mu_pad == k_last == 0)
         if use_momentum:
@@ -386,7 +393,8 @@ def fused_accumulate_select(g: jnp.ndarray, u: jnp.ndarray, v: jnp.ndarray,
         return (u2, v2) + empty + empty
     from repro.kernels import ops as K_ops
     u2, v2, cv, ci, cs = K_ops.fused_ef_topk(
-        g, u, v, jnp.asarray(seg), jnp.asarray(kcap), momentum,
+        g, u, v, _slot_map(layout.n_total, slots), jnp.asarray(kcap),
+        momentum,
         bool(use_momentum), n_cand, block=block, extract=ex,
         interpret=interpret)
     vals_list, idx_list = _merge_candidates(cv, ci, cs, slots)

@@ -45,6 +45,8 @@ from repro.dist.sharding import (
     partition_spec,
 )
 from repro.dist.transport import (
+    EXACT_TOL,
+    Q8_TOL,
     RING_TRANSPORTS,
     TRANSPORTS,
     MeshTransport,

@@ -176,7 +176,7 @@ def checksum_word(payload) -> jnp.ndarray:
 
 
 def _encode_indices_body(idx: jnp.ndarray, plan: PackPlan,
-                         interpret: bool = True):
+                         interpret: bool | None = None):
     assert idx.shape == (plan.k,), (idx.shape, plan)
     idx = idx.astype(jnp.int32)
     if plan.raw_index:
@@ -189,7 +189,7 @@ def _encode_indices_body(idx: jnp.ndarray, plan: PackPlan,
 
 
 def _decode_indices_body(payload, plan: PackPlan,
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: bool | None = None) -> jnp.ndarray:
     if plan.raw_index:
         (idx,) = payload
         return idx
@@ -201,7 +201,7 @@ def _decode_indices_body(payload, plan: PackPlan,
 
 
 def encode_indices(idx: jnp.ndarray, plan: PackPlan,
-                   interpret: bool = True) -> Tuple[jnp.ndarray, ...]:
+                   interpret: bool | None = None) -> Tuple[jnp.ndarray, ...]:
     """The index half of the wire on its own: *sorted-ascending* int32
     ``idx`` (plan.k,) -> (counts, words), or (idx,) on the small-k
     raw-index fallback — plus the trailing checksum word when the plan
@@ -218,7 +218,7 @@ def encode_indices(idx: jnp.ndarray, plan: PackPlan,
 
 
 def decode_indices(payload, plan: PackPlan,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: bool | None = None) -> jnp.ndarray:
     """Inverse of :func:`encode_indices` -> sorted int32 (plan.k,).
     The checksum word (when present) is *stripped*, not verified —
     verification is the guard's job (:func:`validate_payload`), so the
@@ -229,7 +229,7 @@ def decode_indices(payload, plan: PackPlan,
 
 
 def encode_sparse(vals: jnp.ndarray, idx: jnp.ndarray, plan: PackPlan,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     """-> the real wire payload: (counts, words, q, scales), or
     (idx, q, scales) on the small-k raw-index fallback; one trailing
     int32 checksum word covering every prior array when the plan asks
@@ -245,7 +245,7 @@ def encode_sparse(vals: jnp.ndarray, idx: jnp.ndarray, plan: PackPlan,
 
 
 def encode_sparse_fused(vals: jnp.ndarray, idx: jnp.ndarray,
-                        plan: PackPlan, interpret: bool = True):
+                        plan: PackPlan, interpret: bool | None = None):
     """:func:`encode_sparse` with the quantize + bit-plane-pack passes
     collapsed into ONE Pallas launch (``quantize.quantize_pack_fused``):
     the sorted (vals, idx) pair is read from HBM once instead of once
@@ -274,7 +274,7 @@ def encode_sparse_fused(vals: jnp.ndarray, idx: jnp.ndarray,
     return payload
 
 
-def decode_sparse(payload, plan: PackPlan, interpret: bool = True
+def decode_sparse(payload, plan: PackPlan, interpret: bool | None = None
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Inverse of :func:`encode_sparse` -> (vals f32 (k,), idx int32
     (k,)) in index-sorted order: indices bit-exact, values dequantized.
@@ -287,7 +287,7 @@ def decode_sparse(payload, plan: PackPlan, interpret: bool = True
 
 
 def validate_payload(payload, plan: PackPlan, values: bool = True,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """Structural validation of one node's received payload — the guard
     hook the packed transport runs per contribution when a guard policy
     is on.  Checks (each a traced predicate):

@@ -75,7 +75,7 @@ def fake_quantize(x: jnp.ndarray, scale_block: int = SCALE_BLOCK):
 
 def quantize_pack_fused(vals: jnp.ndarray, idx_lo: jnp.ndarray,
                         width: int, scale_block: int = SCALE_BLOCK,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """Fused single-kernel encode of a sorted sparse payload: ONE
     Pallas launch block-quantizes ``vals`` (-> int8 + per-block scales,
     exactly :func:`quantize_i8`'s math) AND bit-plane packs the masked

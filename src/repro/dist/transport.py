@@ -105,7 +105,7 @@ class MeshTransport:
     ae_axes: Tuple[str, ...] = ()
     node_index: Optional[jnp.ndarray] = None   # override for exotic callers
     scale_block: int = Q.SCALE_BLOCK           # int8-wire scale granularity
-    interpret: bool = True                     # Pallas pack kernels on CPU
+    interpret: bool | None = None              # None: from the platform
     guard: str = "off"                         # executor guard policy
     # bucketed-exchange schedule (ring family only): buckets per
     # exchange for the software-pipelined rotate-while-encode schedule;
@@ -179,8 +179,16 @@ class MeshTransport:
         moving only K*k values+indices over the wire, not n.  Always the
         raw f32 wire — deliberately NOT routed through
         ``sparse_gather_packed``, so the packed transport's override
-        never touches exchanges the compressor wants exact."""
-        return self._sparse_gather(vals, idx, n).mean(0)
+        never touches exchanges the compressor wants exact.  All K
+        nodes' pairs scatter-add into ONE (n,) vector: the (K, n) stack
+        of per-node scatters would hold K*n floats on every device."""
+        if not self.axes:
+            return _scatter(vals, idx, n)
+        if vals.shape[0] == 0:
+            return jnp.zeros((n,), vals.dtype)
+        vals_g = self.all_gather(vals)
+        idx_g = self.all_gather(idx)
+        return _scatter(vals_g.reshape(-1), idx_g.reshape(-1), n) / self.K
 
     def sparse_gather_packed(self, vals, idx, n, plan=None):
         """Per-node dense scatters (K, n) of sparse pairs whose *wire
@@ -194,8 +202,9 @@ class MeshTransport:
         return self._sparse_gather(vals, idx, n)
 
     def sparse_mean_packed(self, vals, idx, n, plan=None):
-        """sparse_mean over the packed wire representation."""
-        return self.sparse_gather_packed(vals, idx, n, plan=plan).mean(0)
+        """sparse_mean over the packed wire representation (here the
+        raw wire: the same exchange as ``sparse_mean``)."""
+        return self.sparse_mean(vals, idx, n)
 
 
 @dataclass(frozen=True)
@@ -304,6 +313,9 @@ class RingPackedTransport(RingTransport):
             CH.report_structural(bad)
             out = jnp.where(ok, out, jnp.zeros_like(out))
         return out
+
+    def sparse_mean_packed(self, vals, idx, n, plan=None):
+        return self.sparse_gather_packed(vals, idx, n, plan=plan).mean(0)
 
     def sparse_gather_packed(self, vals, idx, n, plan=None):
         if not self.axes or vals.shape[0] == 0:
@@ -415,7 +427,7 @@ class SimTransport:
     K: int
     ae_axes: Tuple[str, ...] = ()
     scale_block: int = Q.SCALE_BLOCK
-    interpret: bool = True
+    interpret: bool | None = None
     guard: str = "off"
 
     kind = "sim"
@@ -470,6 +482,13 @@ class SimTransport:
 
 TRANSPORTS = ("mesh", "ring", "ring_q8", "ring_hier", "ring_packed", "sim")
 
+# bounds on |global gradient - SimTransport oracle|: the float wires are
+# exact up to summation order; ring_q8's compressed phase pays the
+# wire's bounded requantization error and ring_packed's sparse
+# exchanges their single int8 value quantization
+EXACT_TOL = 1e-5
+Q8_TOL = 2e-3
+
 # the ring family: manual-shard_map transports with structurally measured
 # wire bytes (everything but mesh's XLA-chosen lowering and sim's
 # wire-free emulation)
@@ -481,15 +500,15 @@ def make_transport(kind: str, K: int, axes: Axis = (),
                    scale_block: int = 0,
                    intra_chunk: Optional[int] = None,
                    inter_chunk: Optional[int] = None,
-                   interpret: bool = True,
+                   interpret: bool | None = None,
                    guard: str = "off",
                    wire_buckets: int = 1,
                    fault: Optional[CH.FaultSpec] = None):
     """Factory keyed by CompressionConfig.transport.  ``scale_block``
     (0 = default) sets the int8-wire scale granularity; ``intra_chunk``/
     ``inter_chunk`` tune the hierarchical ring's per-level message size;
-    ``interpret`` interprets the packed wire's Pallas pack kernels (pass
-    False on real TPUs, same contract as ``topk_interpret``).  ``guard``
+    ``interpret`` overrides where the packed wire's Pallas pack kernels
+    run (None: compiled on TPU, interpreted elsewhere).  ``guard``
     (one of ``chaos.GUARD_POLICIES``) arms per-contribution structural
     validation inside the transport; the executor reads the same field
     to decide its own result validation.  ``kind`` may be prefixed

@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 LANE = 128            # TPU lane count: words per plane per grid step
 GROUP = 32            # values packed into one int32 word (one per bit row)
 MAX_WIDTH = 31        # value bits; bit 31 is the int32 sign
@@ -110,7 +112,7 @@ def _encode_fused_kernel(v_ref, x_ref, words_ref, q_ref, s_ref, *,
 
 
 def quantize_pack(vals: jnp.ndarray, idx_lo: jnp.ndarray, width: int,
-                  scale_block: int, eps: float, interpret: bool = True):
+                  scale_block: int, eps: float, interpret: bool | None = None):
     """Single-launch fused encode of a sorted sparse payload:
     ``vals`` (k,) f32 block-quantizes to (q int8 (m, scale_block),
     scales f32 (m,)) and ``idx_lo`` (k,) int32 (already masked to
@@ -145,7 +147,7 @@ def quantize_pack(vals: jnp.ndarray, idx_lo: jnp.ndarray, width: int,
         out_shape=(jax.ShapeDtypeStruct((width, W), jnp.int32),
                    jax.ShapeDtypeStruct((m, scale_block), jnp.int8),
                    jax.ShapeDtypeStruct((m, 1), jnp.float32)),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(v.reshape(m, scale_block), x.reshape(GROUP, W))
     return words, q, scales[:, 0]
 
@@ -169,7 +171,7 @@ def _unpack_tail(w: jnp.ndarray, width: int) -> jnp.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=("width", "interpret"))
-def pack_bits(x: jnp.ndarray, width: int, interpret: bool = True
+def pack_bits(x: jnp.ndarray, width: int, interpret: bool | None = None
               ) -> jnp.ndarray:
     """Pack ``x``: (k,) int32 values in ``[0, 2**width)`` into a
     (width, word_count(k)) int32 bit-plane array.  Values beyond the
@@ -195,7 +197,7 @@ def pack_bits(x: jnp.ndarray, width: int, interpret: bool = True
             in_specs=[pl.BlockSpec((GROUP, LANE), lambda i: (0, i))],
             out_specs=pl.BlockSpec((width, LANE), lambda i: (0, i)),
             out_shape=jax.ShapeDtypeStruct((width, W_main), jnp.int32),
-            interpret=interpret,
+            interpret=interpret_mode(interpret),
         )(cols[:, :W_main]))
     if W > W_main:
         parts.append(_pack_tail(cols[:, W_main:], width))
@@ -203,7 +205,7 @@ def pack_bits(x: jnp.ndarray, width: int, interpret: bool = True
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def unpack_bits(words: jnp.ndarray, k: int, interpret: bool = True
+def unpack_bits(words: jnp.ndarray, k: int, interpret: bool | None = None
                 ) -> jnp.ndarray:
     """Inverse of :func:`pack_bits`: (width, W) int32 planes -> the
     first ``k`` original values, bit-exact."""
@@ -220,7 +222,7 @@ def unpack_bits(words: jnp.ndarray, k: int, interpret: bool = True
             in_specs=[pl.BlockSpec((width, LANE), lambda i: (0, i))],
             out_specs=pl.BlockSpec((GROUP, LANE), lambda i: (0, i)),
             out_shape=jax.ShapeDtypeStruct((GROUP, W_main), jnp.int32),
-            interpret=interpret,
+            interpret=interpret_mode(interpret),
         )(words[:, :W_main]))
     if W > W_main:
         parts.append(_unpack_tail(words[:, W_main:], width))

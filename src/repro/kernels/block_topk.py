@@ -21,50 +21,47 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANE = 128
+from repro.kernels import interpret_mode
+
+from repro.kernels.segmented_topk import (LANE, cand_rows, cand_unpack,
+                                          flat_iota, put_candidate)
 
 
-def _kernel(x_ref, vals_ref, idx_ref, *, k: int, block: int):
+def _block_topk_kernel(x_ref, vals_ref, idx_ref, *, k: int, block: int):
     x = x_ref[0]                                     # (block//LANE, LANE)
     mag = jnp.abs(x)
-    flat_idx = (jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) * LANE
-                + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1))
+    flat_idx = flat_iota(x.shape)
 
-    def body(i, carry):
-        mag, vals, idxs = carry
+    def body(i, mag):
         m = jnp.max(mag)
         # first position achieving the max
-        is_max = (mag == m)
-        pos = jnp.min(jnp.where(is_max, flat_idx, block))
-        val = jnp.sum(jnp.where(flat_idx == pos, x, 0.0))
-        vals = vals.at[i].set(val)
-        idxs = idxs.at[i].set(pos)
-        mag = jnp.where(flat_idx == pos, -1.0, mag)
-        return mag, vals, idxs
+        pos = jnp.min(jnp.where(mag == m, flat_idx, block))
+        put_candidate(vals_ref, i, jnp.sum(jnp.where(flat_idx == pos, x,
+                                                     0.0)))
+        put_candidate(idx_ref, i, pos)
+        return jnp.where(flat_idx == pos, -1.0, mag)
 
-    vals0 = jnp.zeros((k,), x.dtype)
-    idxs0 = jnp.zeros((k,), jnp.int32)
-    _, vals, idxs = jax.lax.fori_loop(0, k, body, (mag, vals0, idxs0))
-    vals_ref[0, :] = vals
-    idx_ref[0, :] = idxs
+    jax.lax.fori_loop(0, k, body, mag)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def block_topk(x: jnp.ndarray, k: int, interpret: bool = True):
+def block_topk(x: jnp.ndarray, k: int, interpret: bool | None = None):
     """x: (n_blocks, block) f32, block % 128 == 0.  Returns per-block
-    (values (n_blocks, k), indices (n_blocks, k) int32, local to block)."""
+    (values (n_blocks, k), indices (n_blocks, k) int32, local to block).
+    Results are stored lane-dense, (cand_rows(k), 128) per block."""
     n_blocks, block = x.shape
     assert block % LANE == 0, block
-    kern = functools.partial(_kernel, k=k, block=block)
+    kern = functools.partial(_block_topk_kernel, k=k, block=block)
+    out_spec = pl.BlockSpec((1, cand_rows(k), LANE), lambda i: (i, 0, 0))
+    out_shape = (n_blocks, cand_rows(k), LANE)
     vals, idx = pl.pallas_call(
         kern,
         grid=(n_blocks,),
         in_specs=[pl.BlockSpec((1, block // LANE, LANE),
                                lambda i: (i, 0, 0))],
-        out_specs=[pl.BlockSpec((1, k), lambda i: (i, 0)),
-                   pl.BlockSpec((1, k), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n_blocks, k), x.dtype),
-                   jax.ShapeDtypeStruct((n_blocks, k), jnp.int32)],
-        interpret=interpret,
+        out_specs=[out_spec, out_spec],
+        out_shape=[jax.ShapeDtypeStruct(out_shape, x.dtype),
+                   jax.ShapeDtypeStruct(out_shape, jnp.int32)],
+        interpret=interpret_mode(interpret),
     )(x.reshape(n_blocks, block // LANE, LANE))
-    return vals, idx
+    return cand_unpack((vals, idx), k)

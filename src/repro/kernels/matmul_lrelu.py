@@ -19,14 +19,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 from jax.experimental.pallas import tpu as pltpu
 
 TM, TN, TK = 128, 128, 128
 LEAKY_SLOPE = 0.01
 
 
-def _kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, nk: int,
-            apply_lrelu: bool):
+def _matmul_lrelu_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, nk: int,
+                         apply_lrelu: bool):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -44,14 +46,15 @@ def _kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, nk: int,
 
 @functools.partial(jax.jit, static_argnames=("apply_lrelu", "interpret"))
 def matmul_bias_lrelu(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
-                      apply_lrelu: bool = True, interpret: bool = True):
+                      apply_lrelu: bool = True, interpret: bool | None = None):
     """x: (M, K), w: (K, N), b: (N,); all dims multiples of the 128 tiles
     (ops.py pads).  Returns lrelu(x @ w + b): (M, N) f32."""
     M, K = x.shape
     N = w.shape[1]
     assert M % TM == 0 and K % TK == 0 and N % TN == 0, (M, K, N)
     nk = K // TK
-    kern = functools.partial(_kernel, nk=nk, apply_lrelu=apply_lrelu)
+    kern = functools.partial(_matmul_lrelu_kernel, nk=nk,
+                             apply_lrelu=apply_lrelu)
     return pl.pallas_call(
         kern,
         grid=(M // TM, N // TN, nk),
@@ -63,5 +66,5 @@ def matmul_bias_lrelu(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
         out_specs=pl.BlockSpec((TM, TN), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((TM, TN), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, w, b.reshape(1, N))

@@ -4,8 +4,8 @@ Handle padding to tile alignment, threshold estimation for the fused EF
 kernel, the im2col lowering of the LGC encoder convs onto the fused
 matmul kernel, and the hierarchical merge for exact global top-k.
 
-``interpret`` defaults to True (CPU validation per the hardware-adaptation
-contract); pass False on real TPUs.
+``interpret`` defaults to None: compiled on a TPU backend, interpreted
+elsewhere (repro.kernels.interpret_mode).
 """
 from __future__ import annotations
 
@@ -40,7 +40,7 @@ def _pad_to(x, mult, value=0.0):
 @functools.partial(jax.jit, static_argnames=("k", "sample_stride",
                                              "interpret"))
 def estimate_threshold(v: jnp.ndarray, k: int, sample_stride: int = 32,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool | None = None) -> jnp.ndarray:
     """DGC sampled-threshold on TPU: top-k over a strided VMEM-resident
     subsample, scaled to the full population.  Exactness is not required —
     the EF accumulators re-absorb anything the threshold misses."""
@@ -50,7 +50,7 @@ def estimate_threshold(v: jnp.ndarray, k: int, sample_stride: int = 32,
     return vals[-1]
 
 
-def sparsify_ef(g, u, v, tau, momentum, interpret: bool = True):
+def sparsify_ef(g, u, v, tau, momentum, interpret: bool | None = None):
     """Fused EF pass over arbitrary-length flat vectors (auto-padded)."""
     n = g.shape[0]
     gp, pad = _pad_to(g, _ef.TILE)
@@ -68,7 +68,7 @@ def sparsify_ef(g, u, v, tau, momentum, interpret: bool = True):
 
 @functools.partial(jax.jit, static_argnames=("k", "block", "interpret"))
 def global_topk(x: jnp.ndarray, k: int, block: int = 64 * 128,
-                interpret: bool = True):
+                interpret: bool | None = None):
     """Exact global top-|x| selection: block-local top-k kernel + merge.
 
     Each block keeps its own top-k candidates (the global winners are a
@@ -100,7 +100,7 @@ def global_topk(x: jnp.ndarray, k: int, block: int = 64 * 128,
                                              "interpret"))
 def segmented_topk(x: jnp.ndarray, seg: jnp.ndarray, kcap: jnp.ndarray,
                    n_cand: int, block: int = SEG_BLOCK,
-                   extract: str = "loop", interpret: bool = True):
+                   extract: str = "loop", interpret: bool | None = None):
     """Candidate sweep over an arbitrary-length flat vector (auto-padded).
 
     ``seg`` maps each element to a selection slot (-1 = not selectable),
@@ -126,7 +126,7 @@ def segmented_topk(x: jnp.ndarray, seg: jnp.ndarray, kcap: jnp.ndarray,
                                              "interpret"))
 def fused_ef_topk(g, u, v, seg, kcap, momentum, use_momentum: bool,
                   n_cand: int, block: int = SEG_BLOCK,
-                  extract: str = "loop", interpret: bool = True):
+                  extract: str = "loop", interpret: bool | None = None):
     """One-sweep EF accumulate + segmented top-k candidates (auto-padded).
 
     u' = m*u + g, v' = v + u' (plain v + g when use_momentum=False) and
@@ -166,7 +166,7 @@ def _im2col_1d(x: jnp.ndarray, ksize: int, stride: int) -> jnp.ndarray:
 
 def conv1d_lrelu(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
                  stride: int, apply_lrelu: bool = True,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: bool | None = None) -> jnp.ndarray:
     """One LGC-AE conv layer on the MXU kernel.  x: (L, C_in); w:
     (ksize, C_in, C_out).  Returns (L_out, C_out) f32."""
     ksize, C_in, C_out = w.shape
@@ -183,7 +183,7 @@ def conv1d_lrelu(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
     return y[:M, :C_out]
 
 
-def lgc_encode_fast(ae_params, g: jnp.ndarray, interpret: bool = True):
+def lgc_encode_fast(ae_params, g: jnp.ndarray, interpret: bool | None = None):
     """Kernel-backed version of core.autoencoder.lgc_encode for a single
     vector g: (L,) with L % 16 == 0.  Returns (L/16, 4)."""
     from repro.core.autoencoder import ENCODER_SPEC

@@ -37,26 +37,59 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 LANE = 128
-BLOCK = 8 * LANE          # default sweep block: one (8, 128) f32 VMEM tile
+SUBLANE = 8
+TILE = SUBLANE * LANE     # one (8, 128) f32 vreg tile
+BLOCK = TILE              # default sweep block: one (8, 128) f32 VMEM tile
 
 
-def select_candidates(x, seg, kcap, n_cand: int, block: int):
+def flat_iota(shape) -> jnp.ndarray:
+    """Row-major flat element index over a (rows, 128) tile."""
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * shape[1]
+            + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+
+
+def cand_rows(n_cand: int) -> int:
+    """Rows of the lane-dense (rows, 128) candidate tile holding
+    ``n_cand`` slots, rounded up to whole (8, 128) tiles."""
+    rows = -(-n_cand // LANE)
+    return -(-rows // SUBLANE) * SUBLANE
+
+
+def put_candidate(ref, i, value):
+    """Store scalar ``value`` at flat candidate slot ``i`` of a
+    (1, rows, 128) ref: read-modify-write of the one (8, 128) tile that
+    holds the slot (the TPU lowering has no scatter)."""
+    r0 = pl.multiple_of((i // TILE) * SUBLANE, SUBLANE)
+    t = ref[0, pl.ds(r0, SUBLANE), :]
+    hit = flat_iota(t.shape) == i % TILE
+    ref[0, pl.ds(r0, SUBLANE), :] = jnp.where(hit, value, t)
+
+
+def select_candidates(x, seg, kcap, n_cand: int, block: int, out, base):
     """Per-block segmented candidate extraction (runs inside a kernel).
 
     x, seg: (block//LANE, LANE) VMEM-resident value / segment-id tiles
     (seg < 0 = not selectable); kcap: (1, n_slots) per-slot top-k caps.
-    Returns (vals (n_cand,), idx (n_cand,) block-local, seg (n_cand,));
-    unused candidate slots carry (0, block, -1).
+    Writes candidate slot i of ``out`` = (vals, idx, seg) refs, each
+    (1, cand_rows(n_cand), 128), in extraction order: value, GLOBAL
+    index ``base`` + block-local index, slot id.  Unused slots carry
+    (0, base + block, -1).
     """
-    flat_idx = (jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) * LANE
-                + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1))
+    flat_idx = flat_iota(x.shape)
     n_slots = kcap.shape[-1]
     slot_iota = jax.lax.broadcasted_iota(jnp.int32, (1, n_slots), 1)
     mag0 = jnp.where(seg >= 0, jnp.abs(x), -1.0)
+    vals_ref, idx_ref, seg_ref = out
+    shape = vals_ref.shape[1:]
+    vals_ref[0] = jnp.zeros(shape, vals_ref.dtype)
+    idx_ref[0] = jnp.full(shape, base + block, jnp.int32)
+    seg_ref[0] = jnp.full(shape, -1, jnp.int32)
 
     def body(i, carry):
-        mag, counts, vals, idxs, segs = carry
+        mag, counts = carry
         m = jnp.max(mag)
         valid = m >= 0.0                     # all-masked block => m == -1
         pos = jnp.min(jnp.where(mag == m, flat_idx, block))
@@ -70,17 +103,13 @@ def select_candidates(x, seg, kcap, n_cand: int, block: int):
         mag = jnp.where(hit, -1.0, mag)
         # slot s reached its cap: its remaining elements can never win
         mag = jnp.where((seg == s) & valid & (cnt >= cap), -1.0, mag)
-        vals = vals.at[i].set(val)
-        idxs = idxs.at[i].set(jnp.where(valid, pos, block))
-        segs = segs.at[i].set(s)
-        return mag, counts, vals, idxs, segs
+        put_candidate(vals_ref, i, val)
+        put_candidate(idx_ref, i, base + jnp.where(valid, pos, block))
+        put_candidate(seg_ref, i, s)
+        return mag, counts
 
-    carry = (mag0, jnp.zeros((1, n_slots), jnp.int32),
-             jnp.zeros((n_cand,), x.dtype),
-             jnp.full((n_cand,), block, jnp.int32),
-             jnp.full((n_cand,), -1, jnp.int32))
-    _, _, vals, idxs, segs = jax.lax.fori_loop(0, n_cand, body, carry)
-    return vals, idxs, segs
+    jax.lax.fori_loop(0, n_cand, body,
+                      (mag0, jnp.zeros((1, n_slots), jnp.int32)))
 
 
 def extract_fn(extract: str):
@@ -97,35 +126,41 @@ def extract_fn(extract: str):
 def sweep_specs(rows: int, n_cand: int, n_slots: int):
     """Shared pallas_call scaffolding for the segmented-sweep kernels
     (this one and sparsify_ef.sparsify_ef_topk): per-block tile spec,
-    per-block candidate spec, broadcast kcap spec."""
+    per-block candidate spec, broadcast kcap spec.  Candidates are
+    stored lane-dense, (cand_rows(n_cand), 128) per block, so every
+    block obeys the TPU lowering's (8, 128) tiling rule."""
     tile = pl.BlockSpec((1, rows, LANE), lambda i: (i, 0, 0))
-    cand = pl.BlockSpec((1, n_cand), lambda i: (i, 0))
+    cand = pl.BlockSpec((1, cand_rows(n_cand), LANE), lambda i: (i, 0, 0))
     kcap = pl.BlockSpec((1, n_slots), lambda i: (0, 0))
     return tile, cand, kcap
 
 
 def cand_out_shapes(n_blocks: int, n_cand: int, dtype):
     """(vals, idx, seg) candidate output shapes for a sweep kernel."""
-    return [jax.ShapeDtypeStruct((n_blocks, n_cand), dtype),
-            jax.ShapeDtypeStruct((n_blocks, n_cand), jnp.int32),
-            jax.ShapeDtypeStruct((n_blocks, n_cand), jnp.int32)]
+    shape = (n_blocks, cand_rows(n_cand), LANE)
+    return [jax.ShapeDtypeStruct(shape, dtype),
+            jax.ShapeDtypeStruct(shape, jnp.int32),
+            jax.ShapeDtypeStruct(shape, jnp.int32)]
 
 
-def _kernel(x_ref, seg_ref, kcap_ref, vals_ref, idx_ref, seg_out_ref, *,
-            n_cand: int, block: int, extract: str):
-    vals, idxs, segs = extract_fn(extract)(x_ref[0], seg_ref[0],
-                                           kcap_ref[...], n_cand, block)
-    base = pl.program_id(0) * block
-    vals_ref[0, :] = vals
-    idx_ref[0, :] = base + idxs
-    seg_out_ref[0, :] = segs
+def cand_unpack(cands, n_cand: int):
+    """Kernel candidate tiles -> (n_blocks, n_cand) arrays."""
+    return tuple(c.reshape(c.shape[0], -1)[:, :n_cand] for c in cands)
+
+
+def _segmented_topk_kernel(x_ref, seg_ref, kcap_ref, vals_ref, idx_ref,
+                           seg_out_ref, *, n_cand: int, block: int,
+                           extract: str):
+    extract_fn(extract)(x_ref[0], seg_ref[0], kcap_ref[...], n_cand, block,
+                        (vals_ref, idx_ref, seg_out_ref),
+                        pl.program_id(0) * block)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("n_cand", "extract", "interpret"))
 def segmented_topk(x: jnp.ndarray, seg: jnp.ndarray, kcap: jnp.ndarray,
                    n_cand: int, extract: str = "loop",
-                   interpret: bool = True):
+                   interpret: bool | None = None):
     """x, seg: (n_blocks, block) f32/int32, block % 128 == 0; kcap:
     (n_slots,) int32 per-slot caps.  Returns per-block candidate triples
     (vals (n_blocks, n_cand), idx (n_blocks, n_cand) in GLOBAL element
@@ -133,15 +168,16 @@ def segmented_topk(x: jnp.ndarray, seg: jnp.ndarray, kcap: jnp.ndarray,
     n_blocks, block = x.shape
     assert block % LANE == 0, block
     rows = block // LANE
-    kern = functools.partial(_kernel, n_cand=n_cand, block=block,
-                             extract=extract)
+    kern = functools.partial(_segmented_topk_kernel, n_cand=n_cand,
+                             block=block, extract=extract)
     tile, cand, kspec = sweep_specs(rows, n_cand, kcap.shape[0])
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid=(n_blocks,),
         in_specs=[tile, tile, kspec],
         out_specs=[cand, cand, cand],
         out_shape=cand_out_shapes(n_blocks, n_cand, x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x.reshape(n_blocks, rows, LANE), seg.reshape(n_blocks, rows, LANE),
       kcap[None])
+    return cand_unpack(out, n_cand)

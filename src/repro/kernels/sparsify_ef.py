@@ -32,15 +32,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.segmented_topk import (cand_out_shapes, extract_fn,
-                                          sweep_specs)
+from repro.kernels import interpret_mode
+
+from repro.kernels.segmented_topk import (cand_out_shapes, cand_unpack,
+                                          extract_fn, sweep_specs)
 
 TILE = 64 * 1024          # elements per VMEM tile (f32: 256 KiB per operand)
 LANE = 128                # TPU lane width; tiles are (TILE//LANE, LANE)
 
 
-def _kernel(g_ref, u_ref, v_ref, tau_ref, m_ref, u_out_ref, v_out_ref,
-            sent_ref):
+def _sparsify_ef_kernel(g_ref, u_ref, v_ref, tau_ref, m_ref, u_out_ref,
+                        v_out_ref, sent_ref):
     g = g_ref[...]
     u = u_ref[...]
     v = v_ref[...]
@@ -58,11 +60,11 @@ def _kernel(g_ref, u_ref, v_ref, tau_ref, m_ref, u_out_ref, v_out_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def sparsify_ef(g: jnp.ndarray, u: jnp.ndarray, v: jnp.ndarray,
                 tau: jnp.ndarray, momentum: jnp.ndarray,
-                interpret: bool = True):
+                interpret: bool | None = None):
     """g, u, v: flat f32 (n,) with n % TILE == 0 (pad in ops.py).
 
-    Returns (u_out, v_out, sent).  interpret=True executes the kernel body
-    on CPU (validation mode); on a real TPU pass interpret=False.
+    Returns (u_out, v_out, sent).  ``interpret`` defaults to the
+    platform (repro.kernels.interpret_mode).
     """
     n = g.shape[0]
     assert n % TILE == 0, n
@@ -72,12 +74,12 @@ def sparsify_ef(g: jnp.ndarray, u: jnp.ndarray, v: jnp.ndarray,
     spec = pl.BlockSpec((rows, LANE), lambda i: (i, 0))
     scalar_spec = pl.BlockSpec((1,), lambda i: (0,))
     out = pl.pallas_call(
-        _kernel,
+        _sparsify_ef_kernel,
         grid=grid,
         in_specs=[spec, spec, spec, scalar_spec, scalar_spec],
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct(shape2d, jnp.float32)] * 3,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(g.reshape(shape2d), u.reshape(shape2d), v.reshape(shape2d),
       tau.reshape(1), momentum.reshape(1))
     u_out, v_out, sent = (o.reshape(n) for o in out)
@@ -103,12 +105,9 @@ def _ef_topk_kernel(g_ref, u_ref, v_ref, seg_ref, kcap_ref, scal_ref,
         v_new = v + g
     u_out_ref[0] = u_new
     v_out_ref[0] = v_new
-    vals, idxs, segs = extract_fn(extract)(v_new, seg_ref[0], kcap_ref[...],
-                                           n_cand, block)
-    base = pl.program_id(0) * block
-    vals_ref[0, :] = vals
-    idx_ref[0, :] = base + idxs
-    seg_out_ref[0, :] = segs
+    extract_fn(extract)(v_new, seg_ref[0], kcap_ref[...], n_cand, block,
+                        (vals_ref, idx_ref, seg_out_ref),
+                        pl.program_id(0) * block)
 
 
 @functools.partial(jax.jit,
@@ -118,7 +117,7 @@ def sparsify_ef_topk(g: jnp.ndarray, u: jnp.ndarray, v: jnp.ndarray,
                      seg: jnp.ndarray, kcap: jnp.ndarray,
                      momentum: jnp.ndarray, use_momentum: bool,
                      n_cand: int, extract: str = "loop",
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """Fused Algorithm 1/2 inner loop + exact segmented selection.
 
     g, u, v, seg: (n_blocks, block); kcap: (n_slots,) int32.  Returns
@@ -142,10 +141,11 @@ def sparsify_ef_topk(g: jnp.ndarray, u: jnp.ndarray, v: jnp.ndarray,
         out_shape=[jax.ShapeDtypeStruct((n_blocks, rows, LANE),
                                         jnp.float32)] * 2 +
                   cand_out_shapes(n_blocks, n_cand, jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(g.reshape(n_blocks, rows, LANE), u.reshape(n_blocks, rows, LANE),
       v.reshape(n_blocks, rows, LANE), seg.reshape(n_blocks, rows, LANE),
       kcap[None], scal)
-    u_out, v_out, cvals, cidx, cseg = out
+    u_out, v_out = out[:2]
     n = n_blocks * block
-    return u_out.reshape(n), v_out.reshape(n), cvals, cidx, cseg
+    return (u_out.reshape(n), v_out.reshape(n)) + cand_unpack(out[2:],
+                                                              n_cand)

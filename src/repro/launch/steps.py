@@ -8,21 +8,22 @@ Two training paths and two serving paths:
   for every (arch x shape) dry-run baseline and for the big configs.
 
 * ``make_lgc_train_step``   — the paper: two sequential regions inside
-  one jit (nesting shard_maps is deliberately avoided: collectives over
-  outer-bound manual axes cannot lower from a nested shard_map on the
-  pinned jax/XLA).  Region 1 computes per-node gradients with a vmap
-  over the node axis under GSPMD auto partitioning (node axis sharded
-  over dp, model axis auto for TP — keeping the node axis means no
-  gradient all-reduce is ever emitted); region 2 is a ``shard_map``
-  fully manual over ALL mesh axes running the gradient compressor per
-  (node x model-shard), so the cross-node reduction carries top-k
-  values (phase 2) or autoencoder encodings (phase 3) instead of the
-  dense gradient — over lax collectives (``transport="mesh"``) or the
-  explicit ring family in repro.dist.collectives (``transport="ring"``,
-  ``"ring_q8"`` — int8 wire — or ``"ring_hier"``; wire bytes measured
-  in all three).  EF/momentum state lives per (node x
-  model-shard) as a (DP, MP, n_local) array.  Params stay replicated
-  across dp shards (paper semantics: every node holds the model).
+  one jit (a layout kept from jax 0.4, which could not lower collectives
+  over outer-bound manual axes from a nested shard_map; merging the
+  regions is a separate, measured change).  Region 1 computes per-node
+  gradients with a vmap over the node axis under GSPMD auto partitioning
+  (node axis sharded over dp, model axis auto for TP — keeping the node
+  axis means no gradient all-reduce is ever emitted); region 2 is a
+  ``shard_map`` fully manual over ALL mesh axes running the gradient
+  compressor per (node x model-shard), so the cross-node reduction
+  carries top-k values (phase 2) or autoencoder encodings (phase 3)
+  instead of the dense gradient — over lax collectives
+  (``transport="mesh"``) or the explicit ring family in
+  repro.dist.collectives (``transport="ring"``, ``"ring_q8"`` — int8
+  wire — or ``"ring_hier"``; wire bytes measured in all three).
+  EF/momentum state lives per (node x model-shard) as a (DP, MP,
+  n_local) array.  Params stay replicated across dp shards (paper
+  semantics: every node holds the model).
 
 * ``make_prefill_step`` / ``make_decode_step`` — serving, plain jit auto;
   decode shards the KV cache batch over dp axes, or the sequence dim when
@@ -227,9 +228,10 @@ def make_lgc_train_step(model: Model, tc: TrainConfig, mesh,
         # dp, so each device computes ITS node's gradient and — crucially
         # — no gradient all-reduce is ever emitted (the node axis is kept,
         # not summed).  The model axis stays auto for TP.  A vmap is used
-        # instead of a dp-manual shard_map because on the pinned jax a
-        # partial-auto shard_map cannot return auto-sharded (TP) gradients
-        # when model > 1 (XLA manual-subgroup check).
+        # instead of a dp-manual shard_map because jax 0.4's partial-auto
+        # shard_map could not return auto-sharded (TP) gradients when
+        # model > 1 (XLA manual-subgroup check); the vmap stays until a
+        # one-region step is measured against it.
         def grad_region(params, batch):
             def node_loss(b):
                 def loss_fn(p):

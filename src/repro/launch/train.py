@@ -60,10 +60,6 @@ def parse_args(argv=None):
                         "sweep: the sequential argmax loop, the bitonic "
                         "partial sort (k-independent depth), or auto "
                         "(bitonic once 8*k_max outgrows the max block)")
-    p.add_argument("--topk-compiled", action="store_true",
-                   help="compile ALL Pallas kernels — selection backends "
-                        "AND the --ae-backend pallas encoder (real TPUs); "
-                        "default interprets them on CPU")
     p.add_argument("--warmup-steps", type=int, default=10)
     p.add_argument("--ae-train-steps", type=int, default=15)
     p.add_argument("--optimizer", default="adamw",
@@ -131,35 +127,16 @@ def parse_args(argv=None):
     return p.parse_args(argv)
 
 
-def main(argv=None):
-    args = parse_args(argv)
-    needed = args.pod_shards * args.data_shards * args.model_shards
-    if args.device_count or needed > 1:
-        os.environ.setdefault(
-            "XLA_FLAGS",
-            f"--xla_force_host_platform_device_count="
-            f"{args.device_count or needed}")
-
-    import jax
-    import jax.tree_util as jtu
-    import numpy as np
-
-    from repro.checkpoint import load_checkpoint, save_checkpoint
+def build_configs(args):
+    """(model config, train config, host mesh) for parsed ``args`` —
+    what :func:`main` trains, for callers that build the same step."""
     from repro.configs import get_arch
     from repro.configs.base import CompressionConfig, TrainConfig
-    from repro.core.phases import phase_for_step
-    from repro.core.rate import rate_report
-    from repro.data import synthetic_token_batches
     from repro.launch.mesh import make_host_mesh
-    from repro.launch.steps import make_auto_train_step, make_lgc_train_step
-    from repro.models import build_model
-    from repro.utils import get_logger
 
-    log = get_logger("train")
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
-    model = build_model(cfg)
     cc = CompressionConfig(method=args.compression, sparsity=args.sparsity,
                            warmup_steps=args.warmup_steps,
                            ae_train_steps=args.ae_train_steps,
@@ -167,7 +144,6 @@ def main(argv=None):
                            topk_backend=args.topk_backend,
                            ae_backend=args.ae_backend,
                            extract_backend=args.extract_backend,
-                           topk_interpret=not args.topk_compiled,
                            wire_buckets=args.wire_buckets,
                            guard=args.guard,
                            guard_checksum=args.guard_checksum,
@@ -182,6 +158,36 @@ def main(argv=None):
                      steps=args.steps, seed=args.seed, compression=cc)
     mesh = make_host_mesh(args.data_shards, args.model_shards,
                           pod=args.pod_shards)
+    return cfg, tc, mesh
+
+
+def main(argv=None):
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    args = parse_args(argv)
+    needed = args.pod_shards * args.data_shards * args.model_shards
+    if args.device_count or needed > 1:
+        os.environ.setdefault(
+            "XLA_FLAGS",
+            f"--xla_force_host_platform_device_count="
+            f"{args.device_count or needed}")
+
+    import jax
+    import jax.tree_util as jtu
+    import numpy as np
+
+    from repro.checkpoint import load_checkpoint, save_checkpoint
+    from repro.core.phases import phase_for_step
+    from repro.core.rate import rate_report
+    from repro.data import synthetic_token_batches
+    from repro.launch.steps import make_auto_train_step, make_lgc_train_step
+    from repro.models import build_model
+    from repro.utils import get_logger
+
+    log = get_logger("train")
+    cfg, tc, mesh = build_configs(args)
+    cc = tc.compression
+    model = build_model(cfg)
     log.info("arch=%s params=%s devices=%d mesh=%s",
              cfg.name, f"{model.param_count():,}", len(jax.devices()),
              dict(zip(mesh.axis_names, mesh.devices.shape)))
@@ -241,6 +247,7 @@ def main(argv=None):
             batch = next(data)
         t0 = time.time()
         for step in range(start_step, args.steps):
+            t_step = time.time()
             phase = phase_for_step(step, cc)
             if phase not in fns:
                 # per-phase wire accounting: bytes are recorded at trace
@@ -267,7 +274,10 @@ def main(argv=None):
                     chaos.raise_on_faults(metrics, step=step)
             if step % args.log_every == 0 or step == args.steps - 1:
                 loss = float(metrics["loss"])
-                rec = {"step": step, "phase": phase, "loss": loss}
+                # wall time of this step, first-call compile included
+                # (the loss fetch waits for the device)
+                rec = {"step": step, "phase": phase, "loss": loss,
+                       "seconds": time.time() - t_step}
                 if guard_on:
                     rec["faults"] = faults_total
                 if fault_ops_by_phase.get(phase):
@@ -309,12 +319,14 @@ def main(argv=None):
             batch = next(data)
         t0 = time.time()
         for step in range(start_step, args.steps):
+            t_step = time.time()
             params, opt_state, metrics = fn(params, opt_state, batch, step)
             batch = next(data)
             if step % args.log_every == 0 or step == args.steps - 1:
                 loss = float(metrics["loss"])
                 history.append({"step": step, "phase": "dense",
-                                "loss": loss})
+                                "loss": loss,
+                                "seconds": time.time() - t_step})
                 log.info("step %4d  loss=%.4f", step, loss)
             if args.checkpoint_every and args.checkpoint_dir \
                     and step and step % args.checkpoint_every == 0:

@@ -26,7 +26,9 @@ from repro.configs.base import MLAConfig, ModelConfig, MoEConfig
 
 
 def _dense_init(key, shape, dtype, scale: Optional[float] = None):
-    fan_in = shape[0] if len(shape) >= 2 else 1
+    # fan-in is the contracted (second-to-last) dim, also for stacked
+    # (E, d_in, d_out) expert weights
+    fan_in = shape[-2] if len(shape) >= 2 else 1
     scale = scale if scale is not None else 1.0 / np.sqrt(fan_in)
     return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
 

@@ -16,21 +16,8 @@ import numpy as np
 
 
 def keystr_path(path) -> str:
-    """'/'-joined key path.  Replacement for
-    ``jax.tree_util.keystr(path, simple=True, separator="/")`` — the
-    ``simple``/``separator`` kwargs do not exist on the jax 0.4.37 pin, so
-    the string is built from the key entries directly."""
-    parts = []
-    for k in path:
-        if hasattr(k, "key"):          # DictKey / FlattenedIndexKey
-            parts.append(str(k.key))
-        elif hasattr(k, "idx"):        # SequenceKey
-            parts.append(str(k.idx))
-        elif hasattr(k, "name"):       # GetAttrKey
-            parts.append(str(k.name))
-        else:
-            parts.append(str(k))
-    return "/".join(parts)
+    """'/'-joined key path, e.g. ``blocks/0/w``."""
+    return jax.tree_util.keystr(path, simple=True, separator="/")
 
 
 def tree_count_params(tree: Any) -> int:

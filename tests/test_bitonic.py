@@ -19,9 +19,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from hypothesis import given, settings, strategies as st
+from jax.experimental import pallas as pl
 
 from repro.core import sparsify as SP
 from repro.kernels import bitonic as B
+from repro.kernels import count_pallas_calls
 from repro.kernels import ops
 
 # Odd sizes: no leaf boundary is a multiple of 128 (lane) or a power of
@@ -48,20 +50,29 @@ ODD_BLOCK = 1152
 
 def test_bitonic_sort_matches_lexsort_with_ties():
     rng = np.random.default_rng(0)
-    n2 = 256
-    keys = jnp.asarray(rng.integers(0, 40, size=(n2,)), jnp.int32)
-    tie = B._iota(n2)
-    payload = jnp.asarray(rng.normal(size=(n2,)), jnp.float32)
+    n2 = 4096                    # 4 tiles: the tile-pair stages run too
+    shape = (n2 // 128, 128)
+    keys = jnp.asarray(rng.integers(0, 40, size=shape), jnp.int32)
+    tie = jnp.arange(n2, dtype=jnp.int32).reshape(shape)
+    payload = jnp.asarray(rng.normal(size=shape), jnp.float32)
 
     def lt(a, b):
         return (a[0] < b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
 
-    sk, stie, sp = B.bitonic_sort([keys, tie, payload], lt, 2, n2)
-    order = np.lexsort((np.asarray(tie), np.asarray(keys)))
-    np.testing.assert_array_equal(np.asarray(sk), np.asarray(keys)[order])
-    np.testing.assert_array_equal(np.asarray(stie), np.asarray(tie)[order])
-    np.testing.assert_array_equal(np.asarray(sp),
-                                  np.asarray(payload)[order])
+    def kernel(k_ref, t_ref, p_ref, ko, to, po):
+        ko[...], to[...], po[...] = k_ref[...], t_ref[...], p_ref[...]
+        B.bitonic_sort([ko, to, po], lt, 2)
+
+    sk, stie, sp = pl.pallas_call(
+        kernel, interpret=True,
+        out_shape=[jax.ShapeDtypeStruct(shape, a.dtype)
+                   for a in (keys, tie, payload)])(keys, tie, payload)
+    keys, tie, payload = (np.asarray(a).reshape(-1)
+                          for a in (keys, tie, payload))
+    order = np.lexsort((tie, keys))
+    np.testing.assert_array_equal(np.asarray(sk).reshape(-1), keys[order])
+    np.testing.assert_array_equal(np.asarray(stie).reshape(-1), tie[order])
+    np.testing.assert_array_equal(np.asarray(sp).reshape(-1), payload[order])
 
 
 def test_next_pow2():
@@ -248,14 +259,13 @@ def test_resolve_extract_auto_threshold():
 def test_bitonic_path_is_one_kernel_launch():
     """Swapping the extractor must not change the sweep's structure:
     still ONE pallas launch for select and for the fused accumulate."""
-    from tests.test_fused_sweep import _count_pallas_calls
     v = jnp.zeros((N,))
     sel = jax.make_jaxpr(lambda x: SP.select_topk(
         x, LAYOUT, backend="fused", extract="bitonic"))(v)
-    assert _count_pallas_calls(sel) == 1
+    assert count_pallas_calls(sel) == 1
     sweep = jax.make_jaxpr(lambda gg, uu, vv: SP.fused_accumulate_select(
         gg, uu, vv, LAYOUT, 0.9, extract="bitonic"))(v, v, v)
-    assert _count_pallas_calls(sweep) == 1
+    assert count_pallas_calls(sweep) == 1
 
 
 def test_big_k_layout_auto_selects_bitonic_and_matches_jnp():

@@ -131,8 +131,12 @@ def test_lgc_rar_reconstruction_tracks_average_after_training():
     # reconstruction error of the trained AE vs the untrained one on a
     # fresh sample of the same family.
     from repro.core import sparsify as SP
+    # the AE's input as the compressor builds it: values at the top-k
+    # support in index order (the canonical sorted support), not in
+    # top-k (magnitude) order
     v = states["v"][0]
-    vals, idx = SP.select_topk(v, comp.layout)
+    _, idx = SP.select_topk(v, comp.layout)
+    vals = SP.gather_at(v, jnp.sort(idx))
 
     def rel_err(ae):
         z = AE.lgc_encode(ae, vals)

@@ -12,6 +12,7 @@ import numpy as np
 from hypothesis import given, settings, strategies as st
 
 from repro.core import sparsify as SP
+from repro.kernels import count_pallas_calls
 
 # Odd sizes: no leaf boundary is a multiple of 128 (lane) or 1024 (block)
 PARAMS_ODD = {
@@ -103,37 +104,20 @@ def test_fused_accumulate_select_matches_three_pass_reference(
 # structural guarantees
 
 
-def _count_pallas_calls(closed):
-    def rec(jaxpr):
-        c = 0
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                c += 1
-            for p in eqn.params.values():
-                vals = p if isinstance(p, (tuple, list)) else (p,)
-                for x in vals:
-                    if isinstance(x, jax.core.ClosedJaxpr):
-                        c += rec(x.jaxpr)
-                    elif isinstance(x, jax.core.Jaxpr):
-                        c += rec(x)
-        return c
-    return rec(closed.jaxpr)
-
-
 def test_fused_path_is_one_kernel_launch():
     """The acceptance property of this refactor: ONE selection launch per
     compress step, not one per leaf (the pallas backend's shape)."""
     v = jnp.zeros((N,))
     fused = jax.make_jaxpr(
         lambda x: SP.select_topk(x, LAYOUT, backend="fused"))(v)
-    assert _count_pallas_calls(fused) == 1
+    assert count_pallas_calls(fused) == 1
     per_leaf = jax.make_jaxpr(
         lambda x: SP.select_topk(x, LAYOUT, backend="pallas"))(v)
-    assert _count_pallas_calls(per_leaf) == len(LAYOUT.compressed)
+    assert count_pallas_calls(per_leaf) == len(LAYOUT.compressed)
     sweep = jax.make_jaxpr(
         lambda gg, uu, vv: SP.fused_accumulate_select(gg, uu, vv, LAYOUT,
                                                       0.9))(v, v, v)
-    assert _count_pallas_calls(sweep) == 1
+    assert count_pallas_calls(sweep) == 1
 
 
 def test_select_topk_last_backend_dispatch_agrees():

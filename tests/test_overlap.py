@@ -38,6 +38,7 @@ from repro.dist import collectives as C
 from repro.dist import packed as PK
 from repro.dist import plan as XP
 from repro.dist import quantize as Q
+from repro.kernels import count_pallas_calls
 
 K = 4
 METHODS = ["none", "sparse_gd", "dgc", "lgc_rar", "lgc_rar_q8", "lgc_ps"]
@@ -70,27 +71,6 @@ def test_bucket_widths_contract():
 # the fused packed-wire encode: bit-exact, one kernel launch
 
 
-def _count_pallas(jaxpr) -> int:
-    n = 0
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            n += 1
-        for v in eqn.params.values():
-            for sub in _sub_jaxprs(v):
-                n += _count_pallas(sub)
-    return n
-
-
-def _sub_jaxprs(v):
-    if hasattr(v, "jaxpr"):                        # ClosedJaxpr
-        return [v.jaxpr]
-    if hasattr(v, "eqns"):                         # Jaxpr
-        return [v]
-    if isinstance(v, (list, tuple)):
-        return [j for x in v for j in _sub_jaxprs(x)]
-    return []
-
-
 @pytest.mark.parametrize("n,k,sb,checksum", [
     (600, 48, 256, False),
     (600, 48, 64, True),
@@ -114,7 +94,7 @@ def test_encode_sparse_fused_bit_exact_single_launch(n, k, sb, checksum):
     # the composed path pays separate quantize + pack passes
     jx = jax.make_jaxpr(
         lambda v, i: PK.encode_sparse_fused(v, i, plan))(vals, idx)
-    assert _count_pallas(jx.jaxpr) == 1, jx
+    assert count_pallas_calls(jx) == 1, jx
     # decodes identically too (same payload, same codec)
     dv, di = PK.decode_sparse(got, plan)
     vs, is_ = PK._sort_pairs(vals, idx)

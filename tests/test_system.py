@@ -33,6 +33,63 @@ print("PASS")
     assert os.path.exists(tmp_path / "ckpt.npz")
 
 
+@pytest.mark.parametrize("method", ["lgc_ps", "lgc_rar"])
+def test_train_driver_single_node_all_three_phases(method):
+    """One node (K = 1, the one-chip deployment): both LGC patterns run
+    warm-up, top-k + AE training and the compressed phase.  The PS
+    pattern stacks its K decoders also at K = 1."""
+    from repro.launch.train import main
+    hist = main(["--arch", "mamba2-130m", "--smoke", "--steps", "5",
+                 "--batch", "2", "--seq", "32", "--compression", method,
+                 "--warmup-steps", "1", "--ae-train-steps", "2",
+                 "--data-shards", "1", "--log-every", "1"])
+    assert [h["phase"] for h in hist] == [
+        "warmup", "topk_ae", "topk_ae", "compressed", "compressed"]
+    assert np.isfinite([h["loss"] for h in hist]).all()
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_location(tmp_path, env_dir):
+    """enable_compile_cache: entries land in JAX_COMPILATION_CACHE_DIR
+    when it is set (and only there), else in <checkout>/.jax_cache."""
+    import subprocess
+    import sys
+    import uuid
+
+    from repro.utils.compile_cache import DEFAULT_DIR
+    probe = f"cache_probe_{uuid.uuid4().hex[:12]}"
+    code = f"""
+import jax, jax.numpy as jnp
+from repro.utils.compile_cache import enable_compile_cache
+enable_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+def {probe}(x):
+    return jnp.sin(x) * 3
+jax.jit({probe})(jnp.ones(5)).block_until_ready()
+"""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"), JAX_PLATFORMS="cpu")
+    own = tmp_path / "cc"
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(own)
+
+    def entries(d):
+        return sorted(d.glob(f"jit_{probe}-*")) if d.exists() else []
+
+    try:
+        subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                       timeout=300)
+        if env_dir:
+            assert entries(own) and not entries(DEFAULT_DIR)
+        else:
+            assert entries(DEFAULT_DIR)
+    finally:
+        for e in entries(DEFAULT_DIR):
+            e.unlink()
+
+
 def test_serve_driver_end_to_end(subproc):
     out = subproc("""
 import sys
