@@ -88,6 +88,7 @@ from repro.dist.packed import PACKED_METHODS  # noqa: F401  (re-export)
 from repro.dist import chaos as CH
 from repro.dist import plan as XP
 from repro.dist.transport import Transport, make_transport
+from repro.utils import scopes as S
 
 Axis = Sequence[str]
 
@@ -135,21 +136,25 @@ class GradientCompressor:
         # sparse_gd is plain residual accumulation, no momentum correction
         return self.cc.method != "sparse_gd"
 
+    @jax.named_scope(S.SELECT)
     def _accumulate(self, u, v, g):
         if not self._use_momentum:
             return u, v + g
         return SP.momentum_correct(u, v, g, self.cc.momentum_correction)
 
+    @jax.named_scope(S.SELECT)
     def _select(self, v):
         return SP.select_topk(v, self.layout,
                               backend=self.cc.topk_backend,
                               extract=self.cc.extract_backend)
 
+    @jax.named_scope(S.SELECT)
     def _select_last(self, v):
         return SP.select_topk_last(v, self.layout,
                                    backend=self.cc.topk_backend,
                                    extract=self.cc.extract_backend)
 
+    @jax.named_scope(S.SELECT)
     def _fused_sweep(self, u, v, g):
         """One-launch accumulate + select over compressed AND exempt-last
         leaves (topk_backend="fused")."""
@@ -158,6 +163,7 @@ class GradientCompressor:
             use_momentum=self._use_momentum,
             extract=self.cc.extract_backend)
 
+    @jax.named_scope(S.AE)
     def _encode(self, ae, x):
         assert self.cc.ae_backend in ("jnp", "pallas"), self.cc.ae_backend
         if self.cc.ae_backend == "pallas":
@@ -167,6 +173,7 @@ class GradientCompressor:
 
     # -- AE online training (phase 2, Section V-B) -----------------------------
 
+    @jax.named_scope(S.AE)
     def _ae_update(self, state, g_nodes, inno_nodes, step, ae_axes=()):
         """One SGD step on the AE params.  g_nodes: (K, mu_pad) — a global
         (replicated) value, so the update is identical on every node.
@@ -312,6 +319,7 @@ class GradientCompressor:
 
         # combined clear: compressed + exempt-last index sets zeroed in a
         # single scatter pass over each accumulator (2 passes, not 4)
+        @jax.named_scope(S.SELECT)
         def clear2(uu, vv, ii, jj):
             return SP.clear_sent_merged(uu, vv, ii, jj, n)
         clear_own = t.pernode(clear2, in_axes=(0, 0, 0, 0))
@@ -422,8 +430,9 @@ class GradientCompressor:
             env = XP.execute(plan, t, feeds)
             gate = self._guard_gate(env, stats)
             idx = env["support"]
-            recs = AE.lgc_decode_ps(state["ae"], env["z_common"],
-                                    env["innovations"])      # (K, mu_pad)
+            with jax.named_scope(S.AE):
+                recs = AE.lgc_decode_ps(state["ae"], env["z_common"],
+                                        env["innovations"])  # (K, mu_pad)
             rec_dense = SP.scatter_to_dense(recs.mean(0), idx, n)
         else:
             # RAR (eq. 17-19): encode -> average (THE all-reduce) -> decode.
@@ -437,7 +446,9 @@ class GradientCompressor:
             env = XP.execute(plan, t, feeds)
             gate = self._guard_gate(env, stats)
             idx = env["support"]
-            rec = AE.lgc_decode_rar(state["ae"], env["encoding"][None])[0]
+            with jax.named_scope(S.AE):
+                rec = AE.lgc_decode_rar(state["ae"],
+                                        env["encoding"][None])[0]
             rec_dense = SP.scatter_to_dense(rec, idx, n)
 
         global_g = rec_dense + g_dense_of(env) + env["exempt_last"]

@@ -74,7 +74,9 @@ def _tally_ops() -> Dict[str, Dict[str, float]]:
 def wire_op(label: str):
     """Attribute wire bytes recorded inside the block to plan op
     ``label`` (the executor wraps each transport call in one of these,
-    which is what feeds ``wire_report(by_op=True)``)."""
+    which is what feeds ``wire_report(by_op=True)``).  The executor
+    enters the device scope ``exchange/<label>`` beside it, so the same
+    label names the op's bytes and its device operations in a trace."""
     prev = getattr(_tally, "label", None)
     _tally.label = label
     try:

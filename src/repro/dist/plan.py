@@ -91,6 +91,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -103,6 +104,7 @@ from repro.dist import chaos as CH
 from repro.dist import collectives as C
 from repro.dist import packed as PK
 from repro.dist import quantize as Q
+from repro.utils import scopes as S
 
 BYTES_F32 = 4
 BYTES_I32 = 4
@@ -372,8 +374,11 @@ def execute(plan: Plan, t, feeds: Dict[str, Callable],
     into underscore-prefixed keys.  Every op label must have exactly one
     feed and vice versa — a step cannot silently skip or invent an
     exchange the plan (and therefore the pricing) doesn't know about.
-    Each transport call runs under ``collectives.wire_op(label)``, so
-    the trace-time tally attributes its bytes to the op.
+    Each transport call runs under ``collectives.wire_op(label)`` and
+    ``jax.named_scope("exchange/<label>")``: the op label names both
+    the trace-time byte tally and the device operations of that call.
+    The feeds run outside both, so their per-node work is not counted
+    as the exchange's.
 
     ``guard`` (default: the transport's own ``guard`` field; one of
     ``chaos.GUARD_POLICIES``) arms per-op result validation: each
@@ -399,12 +404,13 @@ def execute(plan: Plan, t, feeds: Dict[str, Callable],
         args = feeds[op.label](env)
         if not isinstance(args, tuple):
             args = (args,)
+        scope = jax.named_scope(f"{S.EXCHANGE}/{op.label}")
         if guard == "off":
-            with C.wire_op(op.label):
+            with C.wire_op(op.label), scope:
                 env[op.label] = _run_op(op, t, args)
             continue
         sink: list = []
-        with C.wire_op(op.label), CH.structural_sink(sink):
+        with C.wire_op(op.label), scope, CH.structural_sink(sink):
             res = _run_op(op, t, args)
         res, bad = _guard_result(op, res)
         for extra_bad in sink:

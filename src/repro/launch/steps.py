@@ -50,6 +50,7 @@ from repro.launch.input_specs import batch_specs, cache_specs, params_specs
 from repro.launch.mesh import (dp_axes_of, dp_size_of, model_size_of)
 from repro.models.model import Model
 from repro.optim.optimizers import Optimizer, build_optimizer
+from repro.utils import scopes as S
 from repro.utils.tree import tree_flatten_vector, tree_unflatten_vector
 
 
@@ -107,10 +108,12 @@ def make_auto_train_step(model: Model, tc: TrainConfig, mesh,
     def train_step(params, opt_state, batch, step):
         def loss_fn(p):
             return model.loss(p, batch, remat=remat)
-        (loss, metrics), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params)
-        new_params, new_opt = optimizer.update(grads, opt_state, params,
-                                               step)
+        with jax.named_scope(S.FWD_BWD):
+            (loss, metrics), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params)
+        with jax.named_scope(S.OPTIMIZER):
+            new_params, new_opt = optimizer.update(grads, opt_state,
+                                                   params, step)
         return new_params, new_opt, metrics
 
     ps = _shard(mesh, pspecs)
@@ -232,6 +235,7 @@ def make_lgc_train_step(model: Model, tc: TrainConfig, mesh,
         # shard_map could not return auto-sharded (TP) gradients when
         # model > 1 (XLA manual-subgroup check); the vmap stays until a
         # one-region step is measured against it.
+        @jax.named_scope(S.FWD_BWD)
         def grad_region(params, batch):
             def node_loss(b):
                 def loss_fn(p):
@@ -252,6 +256,7 @@ def make_lgc_train_step(model: Model, tc: TrainConfig, mesh,
         # fully manual over every mesh axis: each (node x model-shard)
         # device flattens its local gradient block and the cross-node
         # reduction moves compressed payloads via the configured transport.
+        @jax.named_scope(S.COMPRESS)
         def compress_region(grads_stack, u3, v3, ae_part, step):
             grads_local = jax.tree_util.tree_map(lambda g: g[0],
                                                  grads_stack)
@@ -280,8 +285,9 @@ def make_lgc_train_step(model: Model, tc: TrainConfig, mesh,
             g_global, u3, v3, ae_part, stats = compress_sm(
                 grads_stack, comp_state["u"], comp_state["v"], ae_part,
                 step)
-            new_params, new_opt = optimizer.update(g_global, opt_state,
-                                                   params, step)
+            with jax.named_scope(S.OPTIMIZER):
+                new_params, new_opt = optimizer.update(g_global, opt_state,
+                                                       params, step)
             metrics = jax.tree_util.tree_map(
                 lambda x: jnp.mean(x, axis=0), metrics)
             for k, val in stats.items():

@@ -12,6 +12,7 @@ config of the same architecture family (CPU-tractable).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import time
@@ -124,7 +125,68 @@ def parse_args(argv=None):
                         "target (default: all ops)")
     p.add_argument("--log-every", type=int, default=10)
     p.add_argument("--metrics-out", default="")
+    p.add_argument("--profile-dir", default="",
+                   help="write a profiler trace of --profile-steps here: "
+                        "the device operations under the step's named "
+                        "scopes (repro.utils.scopes) and the host spans "
+                        "train (one per step), data, read_loss and "
+                        "checkpoint")
+    p.add_argument("--profile-steps", default="1:3",
+                   help="a:b, the steps a to b-1 that --profile-dir "
+                        "traces")
     return p.parse_args(argv)
+
+
+class StepClock:
+    """The history's ``seconds``: the mean blocked step time between two
+    loss reads, over the steps between them.  A phase's first call
+    compiles, so ``restart`` before it keeps the steps before out of
+    its reading, and the read after it keeps the compile out of the
+    next one."""
+
+    def __init__(self, step: int):
+        self.restart(step)
+
+    def restart(self, step: int):
+        self.t, self.step = time.time(), step
+
+    def read(self, step: int) -> float:
+        """Seconds per step since the last read, ``step`` included."""
+        now = time.time()
+        sec = (now - self.t) / max(step + 1 - self.step, 1)
+        self.t, self.step = now, step + 1
+        return sec
+
+
+class Profile:
+    """``--profile-dir``: a profiler trace of steps a to b-1, each step in
+    a ``StepTraceAnnotation("train")`` whether traced or not."""
+
+    def __init__(self, out_dir: str, steps: str):
+        self.dir = out_dir
+        a, b = steps.split(":")
+        self.a, self.b = int(a), int(b)
+        self.on = False
+
+    @contextlib.contextmanager
+    def step(self, step: int):
+        import jax
+        if self.dir and step == self.a:
+            jax.profiler.start_trace(self.dir)
+            self.on = True
+        with jax.profiler.StepTraceAnnotation("train", step_num=step):
+            yield
+
+    def after(self, step: int, *outputs, last: bool = False) -> bool:
+        """End the trace after step b-1, or after the ``last`` step, once
+        the outputs given are computed; True where it ended."""
+        import jax
+        if not (self.on and (step >= self.b - 1 or last)):
+            return False
+        jax.block_until_ready(outputs)
+        jax.profiler.stop_trace()
+        self.on = False
+        return True
 
 
 def build_configs(args):
@@ -202,6 +264,7 @@ def main(argv=None):
     rng = jax.random.PRNGKey(args.seed)
     use_lgc = args.compression != "none"
     history = []
+    prof = Profile(args.profile_dir, args.profile_steps)
     from repro.dist import chaos
 
     guard_on = cc.guard != "off"
@@ -246,58 +309,73 @@ def main(argv=None):
             # uninterrupted run would have at this step
             batch = next(data)
         t0 = time.time()
+        clock = StepClock(start_step)
         for step in range(start_step, args.steps):
-            t_step = time.time()
             phase = phase_for_step(step, cc)
-            if phase not in fns:
-                # per-phase wire accounting: bytes are recorded at trace
-                # time, so reset before each phase build and report what
-                # one step of this phase moves per node
-                coll.reset_wire_tally()
-                chaos.reset_fault_tally()
-                fns[phase] = lts.make_step(phase, sds)
-            params, opt_state, comp_state, metrics = fns[phase](
-                params, opt_state, comp_state, batch, step)
-            if step == start_step or phase_for_step(step - 1, cc) != phase:
-                # the first call of a phase is the one that traces it:
-                # both tallies (wire bytes AND injected faults) fill in
-                # at trace time, so sample them here, not at build time
-                fault_ops_by_phase[phase] = chaos.fault_report()
-                wire = coll.wire_report()
-                if wire:
-                    log.info("phase=%s wire bytes/node/step: %s", phase,
-                             {k: int(v) for k, v in wire.items()})
-            batch = next(data)
-            if guard_on:
-                faults_total += _count_faults(metrics)
-                if cc.guard == "fail_fast":
-                    chaos.raise_on_faults(metrics, step=step)
-            if step % args.log_every == 0 or step == args.steps - 1:
-                loss = float(metrics["loss"])
-                # wall time of this step, first-call compile included
-                # (the loss fetch waits for the device)
-                rec = {"step": step, "phase": phase, "loss": loss,
-                       "seconds": time.time() - t_step}
-                if guard_on:
-                    rec["faults"] = faults_total
-                if fault_ops_by_phase.get(phase):
-                    # per-op injected-fault counts ({op label: {fault
-                    # kind: count}}, static trace-time ints) ride along
-                    # next to the loss so a metrics consumer can
-                    # attribute a bad step to the op the spec targeted
-                    rec["fault_ops"] = fault_ops_by_phase[phase]
-                history.append(rec)
-                log.info("step %4d  phase=%-10s loss=%.4f", step, phase,
-                         loss)
-            if args.checkpoint_every and args.checkpoint_dir \
-                    and step and step % args.checkpoint_every == 0:
-                # step+1 = the next step to run on resume; the FULL
-                # state ships, EF residuals included — params alone
-                # would silently drop every coordinate parked in u/v
-                save_checkpoint(
-                    os.path.join(args.checkpoint_dir, "ckpt.npz"),
-                    {"params": params, "opt_state": opt_state,
-                     "comp_state": comp_state}, step + 1)
+            first_call = phase not in fns
+            with prof.step(step):
+                if first_call:
+                    # per-phase wire accounting: bytes are recorded at
+                    # trace time, so reset before each phase build and
+                    # report what one step of this phase moves per node
+                    coll.reset_wire_tally()
+                    chaos.reset_fault_tally()
+                    fns[phase] = lts.make_step(phase, sds)
+                    # the call below compiles: time it alone
+                    jax.block_until_ready(params)
+                    clock.restart(step)
+                params, opt_state, comp_state, metrics = fns[phase](
+                    params, opt_state, comp_state, batch, step)
+                if first_call:
+                    # the first call of a phase is the one that traces
+                    # it: both tallies (wire bytes AND injected faults)
+                    # fill in at trace time, so sample them here, not at
+                    # build time
+                    fault_ops_by_phase[phase] = chaos.fault_report()
+                    wire = coll.wire_report()
+                    if wire:
+                        log.info("phase=%s wire bytes/node/step: %s",
+                                 phase, {k: int(v) for k, v in wire.items()})
+                with jax.profiler.TraceAnnotation("data"):
+                    batch = next(data)
+                logged = step % args.log_every == 0 \
+                    or step == args.steps - 1
+                if guard_on or logged or first_call:
+                    with jax.profiler.TraceAnnotation("read_loss"):
+                        if guard_on:
+                            faults_total += _count_faults(metrics)
+                            if cc.guard == "fail_fast":
+                                chaos.raise_on_faults(metrics, step=step)
+                        if logged or first_call:
+                            loss = float(metrics["loss"])
+                            seconds = clock.read(step)
+                if logged:
+                    rec = {"step": step, "phase": phase, "loss": loss,
+                           "seconds": seconds}
+                    if guard_on:
+                        rec["faults"] = faults_total
+                    if fault_ops_by_phase.get(phase):
+                        # per-op injected-fault counts ({op label: {fault
+                        # kind: count}}, static trace-time ints) ride
+                        # along next to the loss so a metrics consumer
+                        # can attribute a bad step to the op the spec
+                        # targeted
+                        rec["fault_ops"] = fault_ops_by_phase[phase]
+                    history.append(rec)
+                    log.info("step %4d  phase=%-10s loss=%.4f", step,
+                             phase, loss)
+                if args.checkpoint_every and args.checkpoint_dir \
+                        and step and step % args.checkpoint_every == 0:
+                    # step+1 = the next step to run on resume; the FULL
+                    # state ships, EF residuals included — params alone
+                    # would silently drop every coordinate parked in u/v
+                    with jax.profiler.TraceAnnotation("checkpoint"):
+                        save_checkpoint(
+                            os.path.join(args.checkpoint_dir, "ckpt.npz"),
+                            {"params": params, "opt_state": opt_state,
+                             "comp_state": comp_state}, step + 1)
+            if prof.after(step, params, last=step == args.steps - 1):
+                clock.restart(step + 1)          # writing the trace
         log.info("done in %.1fs", time.time() - t0)
         final_tree = {"params": params, "opt_state": opt_state,
                       "comp_state": comp_state}
@@ -318,21 +396,32 @@ def main(argv=None):
         for _ in range(start_step):
             batch = next(data)
         t0 = time.time()
+        clock = StepClock(start_step)
         for step in range(start_step, args.steps):
-            t_step = time.time()
-            params, opt_state, metrics = fn(params, opt_state, batch, step)
-            batch = next(data)
-            if step % args.log_every == 0 or step == args.steps - 1:
-                loss = float(metrics["loss"])
-                history.append({"step": step, "phase": "dense",
-                                "loss": loss,
-                                "seconds": time.time() - t_step})
-                log.info("step %4d  loss=%.4f", step, loss)
-            if args.checkpoint_every and args.checkpoint_dir \
-                    and step and step % args.checkpoint_every == 0:
-                save_checkpoint(
-                    os.path.join(args.checkpoint_dir, "ckpt.npz"),
-                    {"params": params, "opt_state": opt_state}, step + 1)
+            with prof.step(step):
+                params, opt_state, metrics = fn(params, opt_state, batch,
+                                                step)
+                with jax.profiler.TraceAnnotation("data"):
+                    batch = next(data)
+                logged = step % args.log_every == 0 \
+                    or step == args.steps - 1
+                if logged or step == start_step:     # the compiling call
+                    with jax.profiler.TraceAnnotation("read_loss"):
+                        loss = float(metrics["loss"])
+                        seconds = clock.read(step)
+                if logged:
+                    history.append({"step": step, "phase": "dense",
+                                    "loss": loss, "seconds": seconds})
+                    log.info("step %4d  loss=%.4f", step, loss)
+                if args.checkpoint_every and args.checkpoint_dir \
+                        and step and step % args.checkpoint_every == 0:
+                    with jax.profiler.TraceAnnotation("checkpoint"):
+                        save_checkpoint(
+                            os.path.join(args.checkpoint_dir, "ckpt.npz"),
+                            {"params": params, "opt_state": opt_state},
+                            step + 1)
+            if prof.after(step, params, last=step == args.steps - 1):
+                clock.restart(step + 1)          # writing the trace
         log.info("done in %.1fs", time.time() - t0)
         final_tree = {"params": params, "opt_state": opt_state}
 

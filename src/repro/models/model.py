@@ -25,6 +25,7 @@ import numpy as np
 from repro.configs.base import ATTN, CROSS, MAMBA, MLA, ModelConfig
 from repro.models import layers as L
 from repro.models import mamba2 as M
+from repro.utils import scopes as S
 
 
 def _moe_at(cfg: ModelConfig, pos: int) -> bool:
@@ -400,6 +401,7 @@ def _ssd_with_state(x, dt, A, B, C, D, chunk):
     return y, state
 
 
+@jax.named_scope(S.XENT)
 def _chunked_xent(h, w, labels, target_chunk_bytes: int = 2 ** 28):
     """Cross-entropy computed in sequence chunks so the (B,chunk,V) logits
     tensor — not (B,S,V) — bounds activation memory.  The chunk body is
