@@ -26,6 +26,9 @@ from repro.configs.base import ATTN, CROSS, MAMBA, MLA, ModelConfig
 from repro.models import layers as L
 from repro.models import mamba2 as M
 from repro.utils import scopes as S
+from repro.utils.logging import get_logger
+
+log = get_logger(__name__)
 
 
 def _moe_at(cfg: ModelConfig, pos: int) -> bool:
@@ -401,24 +404,52 @@ def _ssd_with_state(x, dt, A, B, C, D, chunk):
     return y, state
 
 
+# Bytes of one chunk's float32 logits in the cross-entropy: bounds the
+# loss's activation memory, whatever the vocabulary.
+XENT_CHUNK_BYTES = 2 ** 27
+
+
+def xent_chunks(rows: int, seq: int, vocab: int,
+                budget: int) -> Tuple[int, int, int]:
+    """The cross-entropy's chunk plan over ``rows`` sequences of ``seq``
+    tokens: ``(c, n_chunks, pad)``.  A chunk takes ``c`` consecutive
+    positions of every row; ``c`` is the largest power of two whose
+    float32 logits ``rows * c * vocab * 4`` fit ``budget`` (at least 1),
+    capped at ``seq`` rounded up to a power of two.  Each row is padded
+    by ``pad`` positions to ``n_chunks * c``."""
+    c = 1 << max(0, (budget // (4 * rows * vocab)).bit_length() - 1)
+    c = min(c, 1 << (seq - 1).bit_length())
+    n_chunks = -(-seq // c)
+    return c, n_chunks, n_chunks * c - seq
+
+
 @jax.named_scope(S.XENT)
-def _chunked_xent(h, w, labels, target_chunk_bytes: int = 2 ** 28):
-    """Cross-entropy computed in sequence chunks so the (B,chunk,V) logits
-    tensor — not (B,S,V) — bounds activation memory.  The chunk body is
-    rematerialized so the backward pass does not retain per-chunk softmax.
+def _chunked_xent(h, w, labels):
+    """Cross-entropy in chunks of ``c`` positions of every row
+    (``xent_chunks``), so one chunk's float32 (B,c,V) logits, at most
+    ``XENT_CHUNK_BYTES``, and not (B,S,V), bound activation memory.
+    Where ``c`` does not divide S the rows are padded with zero
+    positions labelled -1, which add no loss and no gradient.  The chunk
+    body is rematerialized so the backward pass does not retain
+    per-chunk softmax.
+
+    The chunks stay (B,c) blocks rather than runs of the flattened
+    tokens: on the TPU the flattened form made XLA copy a tied head into
+    the transposed layout for the forward loop (77 MB of temporaries for
+    mamba2-130m's head, at every chunk size).
 
     h: (B,S,D); w: (D,V); labels: (B,S) int32, -1 = ignore.
     Returns (sum_xent, n_tokens) both f32 scalars.
     """
     B, S, Dm = h.shape
     V = w.shape[-1]
-    chunk = max(8, min(512, target_chunk_bytes // max(1, 4 * B * V)))
-    while S % chunk:
-        chunk //= 2
-    chunk = max(chunk, 1)
-    n = S // chunk
-    hc = h.reshape(B, n, chunk, Dm).transpose(1, 0, 2, 3)
-    lc = labels.reshape(B, n, chunk).transpose(1, 0, 2)
+    c, n, pad = xent_chunks(B, S, V, XENT_CHUNK_BYTES)
+    log.info("xent: %d x %d tokens, vocab %d: %d chunks of %d x %d "
+             "(%d padded a row)", B, S, V, n, B, c, pad)
+    hc = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
+    hc = hc.reshape(B, n, c, Dm).transpose(1, 0, 2, 3)
+    lc = jnp.pad(labels, ((0, 0), (0, pad)), constant_values=-1)
+    lc = lc.reshape(B, n, c).transpose(1, 0, 2)
 
     @jax.checkpoint
     def body(carry, xs):
@@ -433,9 +464,10 @@ def _chunked_xent(h, w, labels, target_chunk_bytes: int = 2 ** 28):
         xent = ((lse - gold) * valid).sum()
         return (xent_sum + xent, tok_sum + valid.sum()), None
 
-    (xent, n_tok), _ = jax.lax.scan(
-        body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (hc, lc))
+    with jax.named_scope(f"xent_chunks_{n}x{B}x{c}"):
+        (xent, n_tok), _ = jax.lax.scan(
+            body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
+            (hc, lc))
     return xent, n_tok
 
 

@@ -88,21 +88,88 @@ def test_ssd_chunked_matches_sequential_recurrence():
                                rtol=2e-4, atol=2e-4)
 
 
-def test_chunked_xent_matches_direct():
-    from repro.models.model import _chunked_xent
-    B, S, Dm, V = 2, 64, 16, 97   # V deliberately not round
-    h = jax.random.normal(jax.random.PRNGKey(0), (B, S, Dm))
-    w = jax.random.normal(jax.random.PRNGKey(1), (Dm, V)) * 0.1
-    labels = jax.random.randint(jax.random.PRNGKey(2), (B, S), 0, V)
-    labels = labels.at[0, :5].set(-1)    # padding
-    xent, n = _chunked_xent(h, w, labels)
-    logits = h @ w
-    logp = jax.nn.log_softmax(logits)
+def _direct_xent(h, w, labels):
+    """The loss in one piece: log_softmax over (B,S,V), -1 labels
+    masked."""
+    logp = jax.nn.log_softmax(h @ w)
     mask = labels >= 0
-    ref = -jnp.sum(jnp.take_along_axis(
+    return -jnp.sum(jnp.take_along_axis(
         logp, jnp.clip(labels, 0)[..., None], -1)[..., 0] * mask)
-    assert abs(float(xent) - float(ref)) < 1e-2
-    assert int(n) == int(mask.sum())
+
+
+def _xent_inputs(B, S, V, masked=0):
+    h = jax.random.normal(jax.random.PRNGKey(0), (B, S, 16))
+    w = jax.random.normal(jax.random.PRNGKey(1), (16, V)) * 0.1
+    labels = jax.random.randint(jax.random.PRNGKey(2), (B, S), 0, V)
+    return h, w, labels.at[:, :masked].set(-1)
+
+
+# (B, S, V, labels set to -1 at the start of every row); the budget
+# gives chunks of 8 positions a row, so every case runs several
+XENT_CASES = {
+    "round": (2, 64, 128, 5),
+    "seq_not_multiple_of_chunk": (2, 63, 128, 5),     # the MTP head's S-1
+    "vocab_not_round": (2, 64, 97, 5),
+    "chunk_all_masked": (2, 64, 97, 8),
+}
+
+
+def _xent_budget(monkeypatch, model, B, V):
+    monkeypatch.setattr(model, "XENT_CHUNK_BYTES", B * 8 * V * 4)
+
+
+@pytest.mark.parametrize("shape", XENT_CASES.values(), ids=XENT_CASES.keys())
+def test_chunked_xent_matches_direct(shape, monkeypatch):
+    from repro.models import model
+    B, S, V, masked = shape
+    _xent_budget(monkeypatch, model, B, V)
+    c, n, pad = model.xent_chunks(B, S, V, model.XENT_CHUNK_BYTES)
+    assert c == 8 and n > 1 and (pad > 0) == (S % 8 > 0)
+    h, w, labels = _xent_inputs(B, S, V, masked)
+    xent, n_tok = model._chunked_xent(h, w, labels)
+    np.testing.assert_allclose(float(xent),
+                               float(_direct_xent(h, w, labels)), rtol=1e-5)
+    assert int(n_tok) == B * (S - masked) == int((labels >= 0).sum())
+
+
+@pytest.mark.parametrize("shape", XENT_CASES.values(), ids=XENT_CASES.keys())
+def test_chunked_xent_grad_matches_direct(shape, monkeypatch):
+    """Padding and masked chunks add no gradient to h or to the head."""
+    from repro.models import model
+    B, S, V, masked = shape
+    _xent_budget(monkeypatch, model, B, V)
+    h, w, labels = _xent_inputs(B, S, V, masked)
+    got = jax.grad(lambda h, w: model._chunked_xent(h, w, labels)[0],
+                   argnums=(0, 1))(h, w)
+    want = jax.grad(_direct_xent, argnums=(0, 1))(h, w, labels)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=1e-5, atol=1e-6)
+    assert not np.asarray(got[0][:, :masked]).any()
+
+
+@pytest.mark.parametrize("rows,seq,vocab", [
+    (8, 2048, 151936), (8, 2048, 50288), (8, 2047, 151936), (8, 2047, 50288),
+    (1, 1, 97), (2, 63, 97), (2, 64, 97), (4, 4096, 32000), (8, 2047, 2 ** 27)])
+def test_xent_chunks_plan(rows, seq, vocab):
+    from repro.models.model import XENT_CHUNK_BYTES, xent_chunks
+    c, n, pad = xent_chunks(rows, seq, vocab, XENT_CHUNK_BYTES)
+    assert c & (c - 1) == 0
+    assert rows * c * vocab * 4 <= XENT_CHUNK_BYTES or c == 1
+    # the largest such power of two, unless the sequence is shorter
+    assert rows * 2 * c * vocab * 4 > XENT_CHUNK_BYTES or c >= seq
+    assert n * c - pad == seq and 0 <= pad < c
+
+
+@pytest.mark.parametrize("name,vocab,plan", [
+    ("qwen2-1.5b", 151936, (16, 128, 0)),
+    ("mamba2-130m", 50288, (64, 32, 0))])
+def test_xent_chunks_for_the_cells(name, vocab, plan):
+    """8 x 2,048 tokens a chip; the MTP head's 8 x 2,047 pads a position
+    a row."""
+    from repro.models.model import XENT_CHUNK_BYTES, xent_chunks
+    assert xent_chunks(8, 2048, vocab, XENT_CHUNK_BYTES) == plan
+    assert xent_chunks(8, 2047, vocab, XENT_CHUNK_BYTES) == (*plan[:2], 1)
 
 
 def test_moe_dropless_processes_all_assignments():
