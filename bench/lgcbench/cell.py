@@ -49,37 +49,23 @@ def _train_argv(cell: Cell, seed: int) -> List[str]:
 
 def _override(obj, overrides: dict):
     """dataclasses.replace, into nested config groups where the value is
-    a dict."""
+    a dict; a JSON list becomes a tuple where the field holds one (a
+    superblock's ``block_pattern``)."""
     kw = {}
     for k, v in overrides.items():
         cur = getattr(obj, k)
-        kw[k] = _override(cur, v) if isinstance(v, dict) else v
+        if isinstance(v, dict):
+            v = _override(cur, v)
+        elif isinstance(v, list) and isinstance(cur, tuple):
+            v = tuple(v)
+        kw[k] = v
     return dataclasses.replace(obj, **kw)
 
 
-def _check_shape(cfg, model: dict):
-    """The program's model config has to be the configuration file's."""
-    if model["kind"] == "mamba2":
-        s = model["ssm_cfg"]
-        want = {"d_model": model["d_model"], "n_layers": model["n_layer"],
-                "vocab_size": model["vocab_size"],
-                "tie_embeddings": model["tie_embeddings"],
-                "rms_norm_eps": model["rms_norm_eps"],
-                "ssm.d_state": s["d_state"], "ssm.d_conv": s["d_conv"],
-                "ssm.expand": s["expand"], "ssm.head_dim": s["headdim"],
-                "ssm.chunk_size": s["chunk_size"], "d_ff": 0}
-    else:
-        H = model["num_attention_heads"]
-        want = {"d_model": model["hidden_size"],
-                "n_layers": model["num_hidden_layers"],
-                "vocab_size": model["vocab_size"],
-                "tie_embeddings": model["tie_word_embeddings"],
-                "rms_norm_eps": model["rms_norm_eps"],
-                "n_heads": H, "n_kv_heads": model["num_key_value_heads"],
-                "head_dim": model["hidden_size"] // H,
-                "d_ff": model["intermediate_size"],
-                "rope_theta": model["rope_theta"], "qkv_bias": True}
-    want["dtype"] = model["dtype"]
+def _check_shape(cfg, kind, model: dict):
+    """The program's model config has to be the configuration file's, as
+    the model kind maps the file's keys to the config's attributes."""
+    want = {**kind.program_shape(model), "dtype": model["dtype"]}
     bad = {}
     for key, v in want.items():
         obj = cfg
@@ -123,7 +109,7 @@ def build_program(cell: Cell, seed: int) -> Program:
     args = train.parse_args(_train_argv(cell, seed))
     cfg, tc, mesh = train.build_configs(args)
     cfg = _override(cfg, cell.config["overrides"])
-    _check_shape(cfg, cell.config["model"])
+    _check_shape(cfg, cell.kind, cell.config["model"])
     model = build_model(cfg)
     B, S = t["batch_per_chip"] * t["chips"], t["seq_len"]
     sds = {"tokens": jax.ShapeDtypeStruct((B, S), jnp.int32),
@@ -155,7 +141,8 @@ def build_program(cell: Cell, seed: int) -> Program:
 
     def make_weights(key):
         params = p_def.unflatten(list(weights.make(
-            p_specs, jax.random.fold_in(key, 0)).values()))
+            p_specs, jax.random.fold_in(key, 0),
+            getattr(cell.kind, "leaf_init", None)).values()))
         ae = None
         if a_specs:
             ae = a_def.unflatten(list(weights.make(

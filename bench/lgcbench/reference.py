@@ -5,14 +5,11 @@ nothing of the program and takes nothing the program made: the weights
 come from :mod:`weights` (the benchmark's own seeded init) and the
 batches from :mod:`tokens`.  It follows the published descriptions:
 
-* Mamba2 block (arXiv:2405.21060): RMSNorm, ``in_proj`` to
-  ``[z, x, B, C, dt]``, causal depthwise conv with SiLU, the SSD scan in
-  the paper's chunked "minimal" form with a stable segment sum, the ``D``
-  skip, RMSNorm gated by ``silu(z)``, ``out_proj`` and the residual.
-  One group (``ngroups`` 1), as configured.
-* Qwen2 block (arXiv:2407.10671): RMSNorm, grouped-query attention with
-  bias on q, k and v and half-split RoPE, causal softmax, SwiGLU MLP.
-* Tied or untied LM head, mean cross-entropy over every token.
+* the model kind's layers (``bench/kinds/<kind>.py``: ``hidden`` up to
+  the final norm, ``head`` the tied or untied LM head), built from
+  :mod:`primitives`;
+* mean cross-entropy over every token, the head applied in slices of
+  tokens;
 * LGC (arXiv:2103.08870) on the concatenated gradient vector: DGC
   momentum-corrected error feedback, per-layer top-k at the configured
   sparsity, the embedding (first layer) exempt and reduced dense, an
@@ -24,92 +21,31 @@ batches from :mod:`tokens`.  It follows the published descriptions:
 * AdamW with decoupled weight decay under a cosine schedule with linear
   warm-up.
 
-Departures from the published models, shared with the configuration:
+Departure from the published models, shared with the configuration:
 the residual stream is not kept in float32 by the program (the
-reference computes everything in float32), and the Mamba2 block has no
-MLP (``d_intermediate`` 0, as published).
+reference computes everything in float32).
 
-``Numerics`` sets the precision of every matrix product and convolution
-and of the residual stream between layers: :data:`F32` is the
-reference; :func:`fp8_numerics` rounds both operands of each product
-(and the cotangent in the backward pass) and the residual stream to
-float8 e4m3 with one scale per tensor, which is the control that has to
-come out as not correct.
+``Numerics`` (:mod:`primitives`) sets the precision of every matrix
+product and convolution and of the residual stream between layers:
+:data:`F32` is the reference, :func:`fp8_numerics` the control that has
+to come out as not correct.
 """
 from __future__ import annotations
 
+import json
 import math
 from dataclasses import dataclass
 from functools import partial
-from typing import Callable, Dict, List, Tuple
+from pathlib import Path
+from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-HIGHEST = jax.lax.Precision.HIGHEST
-
-
-# ---------------------------------------------------------------------------
-# numerics
-
-
-@dataclass(frozen=True)
-class Numerics:
-    name: str
-    quant: Callable          # applied to each operand of a product
-
-
-def _ident(x):
-    return x
-
-
-F32 = Numerics("f32", _ident)
-
-
-def _fp8_round(x):
-    """Round to float8 e4m3 (4 exponent and 3 mantissa bits) with one
-    scale per tensor, amax to e4m3's largest IEEE-style value, 240.
-    ``reduce_precision`` is used because XLA may drop a
-    convert-to-narrower-and-back pair on the TPU.  The rounding passes
-    gradients straight through; the products round their cotangents
-    themselves (``_qeinsum``)."""
-    x = x.astype(jnp.float32)
-    amax = jax.lax.stop_gradient(jnp.max(jnp.abs(x)))
-    scale = jnp.where(amax > 0, amax / 240.0, 1.0)
-    q = jax.lax.reduce_precision(x / scale, exponent_bits=4,
-                                 mantissa_bits=3) * scale
-    return x + jax.lax.stop_gradient(q - x)
-
-
-def fp8_numerics() -> Numerics:
-    return Numerics("fp8", _fp8_round)
-
-
-def _einsum(nm: Numerics, eq: str, a, b):
-    if nm.quant is _ident:
-        return jnp.einsum(eq, a, b, precision=HIGHEST)
-    return _qeinsum(nm.quant, eq, a, b)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _qeinsum(quant, eq, a, b):
-    return jnp.einsum(eq, quant(a), quant(b), precision=HIGHEST)
-
-
-def _qeinsum_fwd(quant, eq, a, b):
-    qa, qb = quant(a), quant(b)
-    return jnp.einsum(eq, qa, qb, precision=HIGHEST), (qa, qb)
-
-
-def _qeinsum_bwd(quant, eq, res, g):
-    qa, qb = res
-    _, vjp = jax.vjp(lambda x, y: jnp.einsum(eq, x, y, precision=HIGHEST),
-                     qa, qb)
-    return vjp(quant(g))
-
-
-_qeinsum.defvjp(_qeinsum_fwd, _qeinsum_bwd)
+from lgcbench import spec
+from lgcbench.primitives import (F32, HIGHEST, Numerics,  # noqa: F401
+                                 einsum, fp8_numerics)
 
 
 # ---------------------------------------------------------------------------
@@ -147,155 +83,22 @@ def tree_from_paths(items: Dict[str, object]) -> dict:
 # model
 
 
-def _rmsnorm(x, scale, eps):
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * scale
-
-
-def _segsum(x):
-    """x: (..., T) -> (..., T, T) with out[i, j] = sum x[j+1..i] for
-    i >= j and -inf above the diagonal (the paper's stable segsum)."""
-    T = x.shape[-1]
-    xx = jnp.broadcast_to(x[..., None], x.shape + (T,))      # [.., i, j]=x_i
-    below = jnp.tril(jnp.ones((T, T), bool), -1)
-    xx = jnp.where(below, xx, 0.0)
-    seg = jnp.cumsum(xx, axis=-2)
-    return jnp.where(jnp.tril(jnp.ones((T, T), bool)), seg, -jnp.inf)
-
-
-def _ssd(nm, x, dt, A, B, C, chunk):
-    """SSD scan of one sequence, chunked as in arXiv:2405.21060 Listing 1.
-
-    x (S, H, P), dt (S, H), A (H,), B and C (S, N) -> y (S, H, P) with
-    h_t = exp(dt_t A) h_{t-1} + dt_t B_t x_t,  y_t = C_t h_t."""
-    S, H, P = x.shape
-    N = B.shape[-1]
-    Q = min(chunk, S)
-    c = S // Q
-    X = (x * dt[..., None]).reshape(c, Q, H, P)
-    dA = (dt * A[None, :]).reshape(c, Q, H).transpose(2, 0, 1)   # (H,c,Q)
-    Bc = B.reshape(c, Q, N)
-    Cc = C.reshape(c, Q, N)
-    A_cum = jnp.cumsum(dA, axis=-1)                               # (H,c,Q)
-    L = jnp.exp(_segsum(dA))                                      # (H,c,Q,Q)
-    CB = _einsum(nm, "cqn,ckn->cqk", Cc, Bc)
-    Y_diag = _einsum(nm, "hcqk,ckhp->cqhp", L * CB[None], X)
-    decay_states = jnp.exp(A_cum[..., -1:] - A_cum)               # (H,c,Q)
-    states = _einsum(nm, "ckn,ckhp->chpn",
-                     Bc, X * decay_states.transpose(1, 2, 0)[..., None])
-    states = jnp.concatenate([jnp.zeros_like(states[:1]), states], axis=0)
-    chunk_decay = jnp.exp(_segsum(jnp.pad(A_cum[..., -1], ((0, 0), (1, 0)))))
-    new_states = _einsum(nm, "hzc,chpn->zhpn", chunk_decay,
-                         states)                                  # (c+1,..)
-    prev = new_states[:-1]                                        # (c,H,P,N)
-    out_decay = jnp.exp(A_cum).transpose(1, 2, 0)                 # (c,Q,H)
-    Y_off = _einsum(nm, "cqn,chpn->cqhp", Cc, prev) * out_decay[..., None]
-    return (Y_diag + Y_off).reshape(S, H, P)
-
-
-def _mamba_layer(nm, m, p, x):
-    D = m["d_model"]
-    ssm = m["ssm_cfg"]
-    N, P, K = ssm["d_state"], ssm["headdim"], ssm["d_conv"]
-    d_inner = ssm["expand"] * D
-    H = d_inner // P
-    S = x.shape[0]
-    h = _rmsnorm(x, p["norm"]["scale"], m["rms_norm_eps"])
-    zxbcdt = _einsum(nm, "sd,de->se", h, p["in_proj"]["w"])
-    z = zxbcdt[:, :d_inner]
-    xBC = zxbcdt[:, d_inner:2 * d_inner + 2 * N]
-    dt = zxbcdt[:, 2 * d_inner + 2 * N:]
-    xp = jnp.concatenate([jnp.zeros((K - 1, xBC.shape[1])), xBC], axis=0)
-    conv = sum(xp[i:i + S] * p["conv_w"][i] for i in range(K))
-    xBC = jax.nn.silu(conv + p["conv_b"])
-    xs = xBC[:, :d_inner].reshape(S, H, P)
-    Bm = xBC[:, d_inner:d_inner + N]
-    Cm = xBC[:, d_inner + N:]
-    dt = jax.nn.softplus(dt + p["dt_bias"])
-    A = -jnp.exp(p["A_log"])
-    y = _ssd(nm, xs, dt, A, Bm, Cm, ssm["chunk_size"])
-    y = y + xs * p["D"][None, :, None]
-    y = y.reshape(S, d_inner) * jax.nn.silu(z)
-    y = _rmsnorm(y, p["out_norm"]["scale"], m["rms_norm_eps"])
-    return x + _einsum(nm, "se,ed->sd", y, p["out_proj"]["w"])
-
-
-def _rope(x, theta):
-    """x (S, H, d): rotate the two halves of each head by position."""
-    S, _, d = x.shape
-    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def _qwen2_layer(nm, m, p, x):
-    S = x.shape[0]
-    H, KH = m["num_attention_heads"], m["num_key_value_heads"]
-    d = m["hidden_size"] // H
-    eps = m["rms_norm_eps"]
-    a = p["mixer"]
-    h = _rmsnorm(x, a["norm"]["scale"], eps)
-    q = (_einsum(nm, "sd,de->se", h, a["wq"]["w"]) + a["wq"]["b"])
-    k = (_einsum(nm, "sd,de->se", h, a["wk"]["w"]) + a["wk"]["b"])
-    v = (_einsum(nm, "sd,de->se", h, a["wv"]["w"]) + a["wv"]["b"])
-    q = _rope(q.reshape(S, H, d), m["rope_theta"])
-    k = _rope(k.reshape(S, KH, d), m["rope_theta"])
-    v = v.reshape(S, KH, d)
-    G = H // KH
-    q = q.reshape(S, KH, G, d)
-    s = _einsum(nm, "qhgd,khd->hgqk", q, k) / math.sqrt(d)
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    s = jnp.where(causal, s, -jnp.inf)
-    pr = jax.nn.softmax(s, axis=-1)
-    o = _einsum(nm, "hgqk,khd->qhgd", pr, v).reshape(S, H * d)
-    x = x + _einsum(nm, "se,ed->sd", o, a["wo"]["w"])
-    f = p["ffn"]
-    h = _rmsnorm(x, f["norm"]["scale"], eps)
-    g = _einsum(nm, "sd,df->sf", h, f["w_gate"]["w"])
-    u = _einsum(nm, "sd,df->sf", h, f["w_up"]["w"])
-    return x + _einsum(nm, "sf,fd->sd", jax.nn.silu(g) * u,
-                       f["w_down"]["w"])
-
-
-def _n_layers(m):
-    return m["n_layer"] if m["kind"] == "mamba2" else m["num_hidden_layers"]
-
-
-def _tied(m):
-    return m["tie_embeddings"] if m["kind"] == "mamba2" \
-        else m["tie_word_embeddings"]
-
-
 HEAD_CHUNK = 256          # tokens per slice of the (tokens, vocab) logits
 
 
-def row_xent(nm, m, params, tokens, labels):
+def row_xent(nm, m, params, tokens, labels, kind):
     """Summed cross-entropy of one sequence.  params: the nested dict of
-    float32 leaves; ``blocks/p0`` stacks the layers on a leading axis."""
-    x = nm.quant(params["embed"]["w"][tokens])
-    blocks = params["blocks"]["p0"]
-
-    # the residual stream is stored at the numerics' precision between
-    # layers, as the program stores its activations at the configured
-    # dtype
-    if m["kind"] == "mamba2":
-        def layer(x, p):
-            return nm.quant(_mamba_layer(nm, m, p["mixer"], x)), None
-    else:
-        def layer(x, p):
-            return nm.quant(_qwen2_layer(nm, m, p, x)), None
-    x, _ = jax.lax.scan(jax.checkpoint(layer), x, blocks)
-    h = _rmsnorm(x, params["final_norm"]["scale"], m["rms_norm_eps"])
-    w = params["embed"]["w"] if _tied(m) else params["lm_head"]["w"].T
+    float32 leaves; ``blocks/p{i}`` stacks position i of the superblock
+    on a leading axis.  ``kind``: the model kind's module."""
+    h = kind.hidden(nm, m, params, tokens)
+    w = kind.head(m, params)
     S = h.shape[0]
     c = min(S, HEAD_CHUNK)
 
     @jax.checkpoint
     def chunk_xent(hl):
         hc, lc = hl
-        logits = _einsum(nm, "sd,vd->sv", hc, w)
+        logits = einsum(nm, "sd,vd->sv", hc, w)
         lse = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, lc[:, None], axis=-1)[:, 0]
         return jnp.sum(lse - gold)
@@ -304,20 +107,14 @@ def row_xent(nm, m, params, tokens, labels):
     return jnp.sum(per)
 
 
-@partial(jax.jit, static_argnums=(0, 1))
-def _row_grad(nm, m_items, params, tokens, labels):
-    m = _unfreeze(m_items)
-    return jax.value_and_grad(lambda p: row_xent(nm, m, p, tokens,
-                                                 labels))(params)
-
-
-def _freeze(d):
-    return tuple(sorted((k, _freeze(v) if isinstance(v, dict) else v)
-                        for k, v in d.items()))
-
-
-def _unfreeze(t):
-    return {k: _unfreeze(v) if isinstance(v, tuple) else v for k, v in t}
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def _row_grad(nm, m_json, bench_dir, params, tokens, labels):
+    """``m_json``: the model as JSON text, hashable as a static argument
+    whatever lists or groups its configuration holds."""
+    m = json.loads(m_json)
+    kind = spec.kind_module(m["kind"], bench_dir)
+    return jax.value_and_grad(lambda p: row_xent(nm, m, p, tokens, labels,
+                                                 kind))(params)
 
 
 @jax.jit
@@ -325,20 +122,23 @@ def _acc(acc, g):
     return jax.tree_util.tree_map(jnp.add, acc, g)
 
 
-def node_grads(nm, m, params, tokens, labels, K, half=False):
+def node_grads(nm, m, params, tokens, labels, K, half=False,
+               bench_dir=spec.BENCH_DIR):
     """Per-node mean gradients and the mean loss.  Rows are split over K
     nodes in order; each node's gradient is the mean over its tokens.
     Runs one row at a time so the float32 activations fit.  ``half``
-    plants a fault: each node leaves out the second half of its rows."""
+    plants a fault: each node leaves out the second half of its rows.
+    The model kind is found in ``bench_dir``."""
     B, S = tokens.shape
     rows = B // K // 2 if half else B // K
-    mi = _freeze(m)
+    mi = json.dumps(m, sort_keys=True)
     total, grads = 0.0, []
     for node in range(K):
         acc = None
         first = node * (B // K)
         for r in range(first, first + rows):
-            loss, g = _row_grad(nm, mi, params, tokens[r], labels[r])
+            loss, g = _row_grad(nm, mi, bench_dir, params, tokens[r],
+                                labels[r])
             total += float(loss)
             acc = g if acc is None else _acc(acc, g)
         grads.append(jax.tree_util.tree_map(lambda x: x / (rows * S), acc))
@@ -557,7 +357,8 @@ FAULTS = (None, "half_batch", "no_exchange")
 
 
 def run(nm: Numerics, model: dict, train: dict, params0: dict, ae,
-        batches, start_step: int, fault=None, detail: bool = False) -> dict:
+        batches, start_step: int, fault=None, detail: bool = False,
+        bench_dir: Path = spec.BENCH_DIR) -> dict:
     """One training step of the reference per batch, from ``params0``.
 
     ``train``: {"method", "nodes", "sparsity", "momentum",
@@ -576,7 +377,9 @@ def run(nm: Numerics, model: dict, train: dict, params0: dict, ae,
     zeroed error-feedback elements after each step (``v_zero``, the
     elements sent), how many elements of each leaf changed (``moved``)
     and the weights after the last step (``final``, host arrays in leaf
-    order)."""
+    order).
+
+    ``bench_dir`` holds the model kinds (``kinds/<kind>.py``)."""
     if fault not in FAULTS:
         raise ValueError(f"unknown fault {fault!r}")
     stores = tuple(str(np.asarray(x).dtype)
@@ -604,7 +407,8 @@ def run(nm: Numerics, model: dict, train: dict, params0: dict, ae,
         step = start_step + i
         grads, loss = node_grads(nm, model, params, jnp.asarray(b["tokens"]),
                                  jnp.asarray(b["labels"]), K,
-                                 half=fault == "half_batch")
+                                 half=fault == "half_batch",
+                                 bench_dir=bench_dir)
         losses.append(loss)
         if fault == "no_exchange":
             grads = grads[:1]

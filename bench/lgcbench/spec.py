@@ -2,9 +2,25 @@
 
 A cell ``<config>.<traffic>`` reads ``bench/configs/<config>.json``,
 ``bench/traffic/<traffic>.json`` and ``bench/limits/<cell>.json``; a
-per-layer metric ``<name>`` is read by ``bench/metrics/<name>.py``.
-Adding a configuration, a traffic mix, a cell or a metric therefore
+per-layer metric ``<name>`` is read by ``bench/metrics/<name>.py``; a
+configuration's model kind (its ``model.kind``) is described by
+``bench/kinds/<kind>.py``: the program's shape it implies, its counts
+for :mod:`lgcbench.flops` and its float32 reference.  Adding a
+configuration, a traffic mix, a cell, a metric or a model kind therefore
 adds files and entries and edits none.
+
+A kind module defines
+
+* ``program_shape(model)``: {dotted ModelConfig attribute: value} that
+  the program's config has to hold (:mod:`lgcbench.cell`);
+* ``leaf_sizes(model)``, ``matmul_params(model)`` and
+  ``other_flops(model, seq_len)`` (:mod:`lgcbench.flops`);
+* ``hidden(nm, model, params, tokens)``, one sequence's float32 hidden
+  states after the final norm, (S, D), and ``head(model, params)``, the
+  LM head as (V, D) (:mod:`lgcbench.reference`);
+* optionally ``leaf_init(path, shape, key)``: the published
+  initialisation of a leaf the shared rules of :mod:`lgcbench.weights`
+  do not cover, or None.
 """
 from __future__ import annotations
 
@@ -12,6 +28,7 @@ import importlib.util
 import json
 from dataclasses import dataclass
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
@@ -22,6 +39,7 @@ class Cell:
     name: str
     chips: int
     config: dict            # the configuration file
+    kind: ModuleType        # its model kind, bench/kinds/<kind>.py
     traffic: dict           # the traffic file
     limits: dict            # the limits file ({} where not set yet)
     end_to_end: List[dict]  # the end-to-end metrics this cell reports
@@ -58,19 +76,35 @@ def resolve(root: Path, name: str, bench_dir: Path = BENCH_DIR) -> Cell:
         raise ValueError(f"{name}: traffic {w['traffic']!r} is for "
                          f"{traffic['chips']} chips, the cell for "
                          f"{w['chips']}")
-    return Cell(name, w["chips"], config, traffic, limits,
+    return Cell(name, w["chips"], config,
+                kind_module(config["model"]["kind"], bench_dir),
+                traffic, limits,
                 [m for m in bench["end_to_end"] if _reports(m, name)],
                 [m for m in bench["per_layer"] if _reports(m, name)])
 
 
-def metric_reader(name: str, bench_dir: Path = BENCH_DIR) -> Callable:
-    """``read(ctx)`` of ``bench/metrics/<name>.py``."""
-    path = bench_dir / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
+def _load(path: Path, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, bench_dir: Path = BENCH_DIR) -> Callable:
+    """``read(ctx)`` of ``bench/metrics/<name>.py``."""
+    return _load(bench_dir / "metrics" / f"{name}.py",
+                 f"bench_metric_{name}").read
+
+
+def kind_module(kind: str, bench_dir: Path = BENCH_DIR) -> ModuleType:
+    """The model kind ``bench/kinds/<kind>.py``.  A kind without a file
+    is an error."""
+    path = bench_dir / "kinds" / f"{kind}.py"
+    if not path.exists():
+        known = sorted(p.stem for p in (bench_dir / "kinds").glob("*.py"))
+        raise KeyError(f"no model kind {kind!r} in {bench_dir / 'kinds'}; "
+                       f"known: {known}")
+    return _load(path, f"bench_kind_{kind}")
 
 
 def peaks(device_kind: str, bench_dir: Path = BENCH_DIR) -> Dict:

@@ -8,7 +8,9 @@ embedding), RMSNorm scales one, biases zero, Mamba2's ``A_log`` as
 log(U[1, 16]), its ``dt_bias`` as the inverse softplus of a step size
 log-uniform in [1e-3, 1e-1], ``D`` one, and its depthwise conv uniform
 in +-1/sqrt(kernel) (PyTorch's default).  Autoencoder kernels are He
-normal, their biases zero.
+normal, their biases zero.  A model kind may give a leaf these rules do
+not cover its own published initialisation (``leaf_init`` of its
+module, ``bench/kinds/<kind>.py``).
 """
 from __future__ import annotations
 
@@ -24,7 +26,14 @@ def seed_key(seed: int):
     return jax.random.PRNGKey(int(word) & 0x7FFFFFFF)
 
 
-def _leaf(key, path: str, shape, dtype):
+def _leaf(key, path: str, shape, dtype, leaf_init=None):
+    x = None if leaf_init is None else leaf_init(path, shape, key)
+    if x is None:
+        x = _shared(key, path, shape)
+    return x.astype(dtype)
+
+
+def _shared(key, path: str, shape):
     parts = path.split("/")
     name = parts[-1]
     f32 = jnp.float32
@@ -53,12 +62,15 @@ def _leaf(key, path: str, shape, dtype):
     else:
         fan_in = shape[-2]
         x = jax.random.normal(key, shape, f32) / np.sqrt(fan_in)
-    return x.astype(dtype)
+    return x
 
 
-def make(specs, key):
+def make(specs, key, leaf_init=None):
     """``specs``: [(path, shape, dtype)] -> {path: array}.  Meant to be
     called under ``jax.jit`` with the key as an argument, so one program
-    serves every seed and makes every leaf on the device."""
-    return {path: _leaf(jax.random.fold_in(key, i), path, shape, dtype)
+    serves every seed and makes every leaf on the device.  ``leaf_init``
+    (path, shape, key) -> float32 array or None: a model kind's own
+    rule, tried before the shared ones."""
+    return {path: _leaf(jax.random.fold_in(key, i), path, shape, dtype,
+                        leaf_init)
             for i, (path, shape, dtype) in enumerate(specs)}

@@ -109,9 +109,9 @@ def result(cell, seed: int, seconds: float, trace: bool, devices,
                 "trace": tr, "steps": r.traced_steps, "chips": cell.chips,
                 "tokens_per_step": tokens_per_step,
                 "flops_per_token": flops.flops_per_token(
-                    model, t["seq_len"])["total"],
+                    model, t["seq_len"], bench_dir)["total"],
                 "least_post_grad_bytes": flops.least_post_grad_bytes(
-                    model, t["method"]),
+                    model, t["method"], bench_dir),
                 "peaks": spec.peaks(devices[0].device_kind, bench_dir),
                 "wire": r.wire,
             }
@@ -130,7 +130,7 @@ def result(cell, seed: int, seconds: float, trace: bool, devices,
     t0 = time.time()
     train = C.reference_train(t)
     ref = reference.run(reference.F32, model, train, r.params0, r.ae0,
-                        r.batches, t["start_step"])
+                        r.batches, t["start_step"], bench_dir=bench_dir)
     prog = {"loss": r.losses, "grad": r.readings["grad"],
             "change": C.change_norms(r.readings["params3"], r.params0)}
     if "ef_u" in r.readings:
